@@ -59,8 +59,8 @@ def _bench_paged_decode(lines, records):
     ctx = ps * maxp
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(b * maxp + 1, ps, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(b * maxp + 1, ps, hkv, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(hkv, b * maxp + 1, ps, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(hkv, b * maxp + 1, ps, d)), jnp.float32)
     bt = jnp.asarray(1 + rng.permutation(b * maxp).reshape(b, maxp), jnp.int32)
     lens = jnp.full((b,), ctx, jnp.int32)
     kc = jnp.asarray(rng.normal(size=(b, ctx, hkv, d)), jnp.float32)

@@ -32,6 +32,8 @@ def main() -> None:
                          "sections that support tracing (serving)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     print("name,us_per_call,derived")
     from benchmarks import bench_kernels, bench_paper_figs, bench_accuracy, \
         bench_serving, roofline_table

@@ -3,6 +3,9 @@ eligible projection weight with a ``QuantizedLinear`` (concrete arrays, via
 the GPTQ algorithm + captured Hessians) or with abstract ShapeDtypeStructs
 (for the dry-run's serving memory/roofline analysis).
 
+``random_quantized_params`` draws a serving-ready tree straight from a seed
+at the quantized shapes (random weights at published widths, no dense copy).
+
 Eligible = transformer projection matrices (attention, FFN, SSM, per-expert
 tensors). Embeddings, output head, norms, routers, conv and SSM scan tensors
 stay fp (matching AutoGPTQ / the paper's vLLM setup).
@@ -14,6 +17,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.gptq import GPTQConfig, QuantizedLinear, gptq_quantize
 
@@ -77,6 +81,59 @@ def abstract_quantized_params(abstract_params, cfg_gptq: GPTQConfig,
             perm=None, bias=None, shape=(k, n), group_size=g)
 
     return jax.tree_util.tree_map_with_path(f, abstract_params)
+
+
+def _is_ql(x) -> bool:
+    return isinstance(x, QuantizedLinear)
+
+
+# a packed int32 word whose eight nibbles are all the mid-grid zero point 8
+_MID_ZEROS = int(np.array(0x88888888, np.uint32).view(np.int32))
+# std of (q - 8) for q uniform on [0, 15]: sqrt((16**2 - 1) / 12)
+_NIBBLE_STD = float(np.sqrt((16 ** 2 - 1) / 12))
+
+
+def _random_quantized(key, ql: QuantizedLinear) -> QuantizedLinear:
+    """Random int4 weights at ``ql``'s shapes: uniform nibbles around the
+    mid zero point, group scales sized so the dequantized weight has the
+    dense init's std (K ** -0.5)."""
+    kq, ks = jax.random.split(key)
+    qweight = jax.lax.bitcast_convert_type(
+        jax.random.bits(kq, ql.qweight.shape, jnp.uint32), jnp.int32)
+    base = ql.shape[0] ** -0.5 / _NIBBLE_STD
+    scales = (base * jax.random.uniform(ks, ql.scales.shape, jnp.float32,
+                                        0.75, 1.25)).astype(ql.scales.dtype)
+    qzeros = jnp.full(ql.qzeros.shape, _MID_ZEROS, jnp.int32)
+    return dataclasses.replace(ql, qweight=qweight, scales=scales,
+                               qzeros=qzeros)
+
+
+def random_quantized_params(model, cfg_gptq: GPTQConfig, seed: int,
+                            scale_dtype=jnp.bfloat16):
+    """Serving parameters drawn straight from ``seed``: every eligible
+    projection is random int4 at the shapes of
+    ``abstract_quantized_params``; embeddings and norms come from
+    ``model.init``.  One jitted program builds the tree, and the dense
+    projection weights ``model.init`` would draw are dead code there, so
+    the device never holds a bf16 copy of them (a published-width model
+    fits beside its KV pool)."""
+    abstract = abstract_quantized_params(model.abstract_params(), cfg_gptq,
+                                         scale_dtype)
+
+    def build(key):
+        k_dense, k_quant = jax.random.split(key)
+        dense = model.init(k_dense)
+        leaves, treedef = jax.tree_util.tree_flatten(abstract,
+                                                     is_leaf=_is_ql)
+        keys = jax.random.split(k_quant, len(leaves))
+        quant = jax.tree_util.tree_unflatten(
+            treedef, [_random_quantized(k, leaf) if _is_ql(leaf) else None
+                      for k, leaf in zip(keys, leaves)])
+        return jax.tree_util.tree_map(
+            lambda a, d, q: q if _is_ql(a) else d, abstract, dense, quant,
+            is_leaf=_is_ql)
+
+    return jax.jit(build)(jax.random.key(seed))
 
 
 def quantize_params(params, hessians: dict[str, Any] | None,

@@ -37,6 +37,7 @@ from repro.core.perf_model import gptq_matmul_cost
 from repro.kernels import gptq_gemv as _gemv
 from repro.kernels import gptq_matmul as _gm
 from repro.kernels.gptq_gemv import GEMV_M_MAX
+from repro.kernels.lane import resolve_interpret
 
 ENV_CACHE = "REPRO_AUTOTUNE_CACHE"
 DEFAULT_CACHE = os.path.join(os.path.expanduser("~"), ".cache", "repro",
@@ -66,11 +67,11 @@ def _lane(m: int) -> str:
 
 
 def cache_key(m: int, k: int, n: int, group_size: int,
-              strategy: KernelStrategy, *, interpret: bool = True) -> str:
+              strategy: KernelStrategy, *, interpret: bool | None = None) -> str:
     """Includes the execution mode: interpreter-mode timings (CPU dev box)
     must never be reused for compiled-TPU runs — the two wall-clock signals
     are uncorrelated, so each mode tunes and caches independently."""
-    mode = "interp" if interpret else "compiled"
+    mode = "interp" if resolve_interpret(interpret) else "compiled"
     return f"{m}x{k}x{n}:g{group_size}:{strategy.name}:{_lane(m)}:{mode}"
 
 
@@ -143,7 +144,7 @@ def _time_call(fn, reps: int = TIMING_REPS) -> float:
 
 def autotune_block_sizes(m: int, k: int, n: int, group_size: int,
                          strategy: KernelStrategy = OPT4GPTQ, *,
-                         interpret: bool = True,
+                         interpret: bool | None = None,
                          max_timed: int = MAX_TIMED
                          ) -> tuple[int, int, int]:
     """Enumerate -> prune -> time; returns the fastest (bm, bn, bk)."""
@@ -215,7 +216,7 @@ def _q_chunk_lookup(key: str, path: str | None, tune) -> int:
 
 def get_block_sizes(m: int, k: int, n: int, group_size: int,
                     strategy: KernelStrategy = OPT4GPTQ, *,
-                    interpret: bool = True,
+                    interpret: bool | None = None,
                     path: str | None = None) -> tuple[int, int, int]:
     """Cached autotune lookup: memory -> JSON file -> tune (and persist).
 
@@ -254,8 +255,8 @@ Q_CHUNK_CANDIDATES = (128, 256, 512)
 
 
 def q_chunk_cache_key(s: int, h: int, hkv: int, d: int, page_size: int, *,
-                      interpret: bool = True) -> str:
-    mode = "interp" if interpret else "compiled"
+                      interpret: bool | None = None) -> str:
+    mode = "interp" if resolve_interpret(interpret) else "compiled"
     return f"qchunk:s{s}:h{h}:kv{hkv}:d{d}:ps{page_size}:{mode}"
 
 
@@ -265,7 +266,7 @@ def q_chunk_candidates(s: int) -> list[int]:
 
 
 def autotune_q_chunk(s: int, h: int, hkv: int, d: int, page_size: int, *,
-                     interpret: bool = True) -> int:
+                     interpret: bool | None = None) -> int:
     """Wall-clock the chunked paged-prefill kernel per candidate tile height
     on synthetic pools and return the fastest ``q_chunk``."""
     from repro.kernels import paged_attention as PA
@@ -278,9 +279,9 @@ def autotune_q_chunk(s: int, h: int, hkv: int, d: int, page_size: int, *,
     n_pages = -(-s // page_size)
     q = jnp.asarray(rng.normal(size=(1, s, h, d)).astype(np.float32))
     kp = jnp.asarray(
-        rng.normal(size=(n_pages + 1, page_size, hkv, d)).astype(np.float32))
+        rng.normal(size=(hkv, n_pages + 1, page_size, d)).astype(np.float32))
     vp = jnp.asarray(
-        rng.normal(size=(n_pages + 1, page_size, hkv, d)).astype(np.float32))
+        rng.normal(size=(hkv, n_pages + 1, page_size, d)).astype(np.float32))
     bt = jnp.arange(1, n_pages + 1, dtype=jnp.int32)[None]
     start = jnp.zeros((1,), jnp.int32)
     lengths = jnp.full((1,), s, jnp.int32)
@@ -295,7 +296,7 @@ def autotune_q_chunk(s: int, h: int, hkv: int, d: int, page_size: int, *,
 
 
 def get_q_chunk(s: int, h: int, hkv: int, d: int, page_size: int, *,
-                interpret: bool = True, path: str | None = None) -> int:
+                interpret: bool | None = None, path: str | None = None) -> int:
     """Cached ``q_chunk`` lookup: memory -> JSON file -> tune (and persist)."""
     key = q_chunk_cache_key(s, h, hkv, d, page_size, interpret=interpret)
     return _q_chunk_lookup(

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.lane import resolve_interpret
+
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             scale, causal, bq, bk):
@@ -61,8 +63,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool | None = None) -> jnp.ndarray:
     """q, k, v: (B, S, H, D) with H == Hkv (repeat GQA outside). -> (B,S,H,D)."""
+    interpret = resolve_interpret(interpret)
     b, sq, h, d = q.shape
     sk = k.shape[1]
     bq = min(bq, sq)

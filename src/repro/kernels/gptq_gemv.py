@@ -6,8 +6,10 @@ one sublane tile) while K and N are model-sized — so the general
 (mi, ni, ki) cell.  This kernel is specialized for that shape:
 
 * **N-major grid** ``(N // bn,)`` — one program per output column block; the
-  full K reduction happens inside the program (a VMEM ``fori_loop`` over bk
-  chunks), so there is no K grid dimension at all.
+  full K reduction happens inside the program (a static loop over K
+  chunks), so there is no K grid dimension at all.  The served strategy
+  runs ``gptq_matmul.fused_full_k`` with one 8-row sublane tile of M; the
+  kernel below serves the ablation strategies (interpret mode only).
 * **SMB** — the fp32 accumulator lives in VMEM scratch for the whole
   reduction and is written back exactly once.  This grid *is* the SMB
   optimization: strategies with ``accum_vmem=False`` intentionally keep the
@@ -39,8 +41,10 @@ from repro.core.opt_strategies import KernelStrategy, OPT4GPTQ
 from repro.kernels import gptq_matmul as _gm
 from repro.kernels.gptq_matmul import (NIB, _compute_tile, _dequant_tile,
                                        _round_up, _unpack_cols_block,
-                                       _unpack_rows_block, pad_cols,
+                                       _unpack_rows_block, full_k_strategy,
+                                       fused_full_k, pad_cols,
                                        resolve_block_sizes)
+from repro.kernels.lane import resolve_interpret
 
 # M at or below this routes to the GEMV lane (ops.gptq_linear dispatcher):
 # one padded sublane tile, the paper's decode regime.
@@ -83,7 +87,7 @@ def gptq_gemv(x: jnp.ndarray, qweight: jnp.ndarray, scales: jnp.ndarray,
               qzeros: jnp.ndarray, bias: jnp.ndarray | None = None, *,
               group_size: int, strategy: KernelStrategy = OPT4GPTQ,
               bn: int = 256, bk: int = 512, out_dtype=None,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: bool | None = None) -> jnp.ndarray:
     """Fused GPTQ GEMV: y = x @ dequant(qweight) + bias for small-M decode.
 
     x: (M, K) with M <= GEMV_M_MAX (padded to a sublane tile).  qweight is
@@ -92,9 +96,14 @@ def gptq_gemv(x: jnp.ndarray, qweight: jnp.ndarray, scales: jnp.ndarray,
     without the fused+VMEM-accumulator structure delegate to ``gptq_matmul``
     (their ablation semantics are grid-level, which this lane removes).
     """
+    interpret = resolve_interpret(interpret)
     m, k = x.shape
     n = scales.shape[1]
     out_dtype = out_dtype or x.dtype
+    if full_k_strategy(strategy):
+        return fused_full_k(x, qweight, scales, qzeros, bias,
+                            group_size=group_size, bm=8, bn=bn, bk=bk,
+                            out_dtype=out_dtype, interpret=interpret)
     if not (strategy.fused and strategy.accum_vmem):
         y = _gm.gptq_matmul(x, qweight, scales, qzeros,
                             group_size=group_size, strategy=strategy,

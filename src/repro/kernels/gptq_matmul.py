@@ -2,6 +2,23 @@
 
 y[M, N] = x[M, K] @ dequant(qweight[K//8, N], scales[G, N], qzeros[G, N//8])
 
+The served strategy (all three optimizations on, ``OPT4GPTQ``) runs the
+**full-K kernel** (``fused_full_k``), shared with the decode GEMV lane:
+
+* grid ``(M // bm, N // bn)``; each program holds the whole K extent of its
+  weight column block in VMEM and reduces it in a static loop over chunks
+  of 128 packed rows, so every block satisfies the TPU (8, 128) tiling rule
+  at any K (K=2560 is 2.5 lane tiles of packed rows);
+* the nibble unpack never reshapes across sublanes: the *activations* are
+  de-interleaved instead (``x[:, 8r + j]`` becomes plane ``j``), so nibble
+  ``j`` of packed row ``r`` multiplies plane ``j`` — eight MXU dots per chunk;
+* zero points are unpacked to (G, N) int8 outside the kernel (G = K/group
+  rows: about 1/64 of the weight bytes), so their block is lane-aligned
+  where the packed (G, N//8) layout would need bn to be a multiple of 1024.
+
+The other strategies are the paper's ablation variants, kept on the
+K-blocked grid below; they are validated in interpret mode only.
+
 Strategy flags (core/opt_strategies.py) select the paper's ablation variants:
 
 * SMB  (``accum_vmem``): fp32 VMEM scratch accumulator, K-innermost grid,
@@ -32,24 +49,33 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
 from repro.core.opt_strategies import KernelStrategy, OPT4GPTQ
+from repro.kernels.lane import resolve_interpret
 
 NIB = packing.NIBBLES_PER_WORD
+LANE = 128
+CHUNK_ROWS = 128           # packed rows per K step of the full-K kernel
+VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def nibble(q, j: int):
+    """Nibble ``j`` of every int32 word of ``q``, as int32 in [0, 15].
+
+    A logical shift on int32 words: Mosaic has no uint32 -> float cast, so
+    the unpack never leaves int32 (the sign bit of nibble 7 is shifted out,
+    not extended)."""
+    return jax.lax.shift_right_logical(q, jnp.int32(4 * j)) & 0xF
 
 
 def _unpack_rows_block(qw, bk):
     """(bk//8, bn) int32 -> (bk, bn) f32 nibble values, vector shift/mask."""
-    q = qw.astype(jnp.uint32)
-    shifts = (4 * jnp.arange(NIB, dtype=jnp.uint32))[None, :, None]
-    nib = (q[:, None, :] >> shifts) & jnp.uint32(0xF)
-    return nib.reshape(bk, q.shape[-1]).astype(jnp.float32)
+    nib = jnp.stack([nibble(qw, j) for j in range(NIB)], axis=1)
+    return nib.reshape(bk, qw.shape[-1]).astype(jnp.float32)
 
 
 def _unpack_cols_block(qz, bn):
     """(gk, bn//8) int32 -> (gk, bn) f32 zero points."""
-    q = qz.astype(jnp.uint32)
-    shifts = (4 * jnp.arange(NIB, dtype=jnp.uint32))[None, None, :]
-    nib = (q[:, :, None] >> shifts) & jnp.uint32(0xF)
-    return nib.reshape(q.shape[0], bn).astype(jnp.float32)
+    nib = jnp.stack([nibble(qz, j) for j in range(NIB)], axis=2)
+    return nib.reshape(qz.shape[0], bn).astype(jnp.float32)
 
 
 def _dequant_tile(w_nib, s, z, bk, group_size):
@@ -151,6 +177,115 @@ def _kernel_matmul(x_ref, w_ref, o_ref, acc_ref):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def full_k_strategy(strategy: KernelStrategy) -> bool:
+    """The served strategy: fused, VMEM accumulation, packed loads, MXU."""
+    return (strategy.fused and strategy.accum_vmem and strategy.packed_loads
+            and strategy.mxu)
+
+
+def _kernel_full_k(x_ref, qw_ref, s_ref, z_ref, b_ref, o_ref, *, chunks,
+                   rows_per_group):
+    """One (bm, bn) output block over the whole K extent.
+
+    ``x_ref`` is (8, bm, K//8): plane ``j`` holds the activations of K rows
+    ``8r + j``, so it pairs with nibble ``j`` of packed weight row ``r``.
+    ``chunks`` are static (start, end) packed-row spans; each lies inside
+    one quantization group or starts on a group boundary, so its scales and
+    zeros are group rows repeated ``rows_per_group`` times."""
+    bm, bn = o_ref.shape
+    cdt = jnp.bfloat16 if x_ref.dtype == jnp.bfloat16 else jnp.float32
+    s_all = s_ref[...].astype(jnp.float32)
+    z_all = z_ref[...].astype(jnp.float32)
+    acc = jnp.zeros((bm, bn), jnp.float32)
+    for r0, r1 in chunks:
+        g0, g1 = r0 // rows_per_group, -(-r1 // rows_per_group)
+        s, z = s_all[g0:g1], z_all[g0:g1]
+        if g1 - g0 > 1:
+            rows = (g1 - g0, rows_per_group, bn)
+            s = jnp.broadcast_to(s[:, None], rows).reshape(-1, bn)
+            z = jnp.broadcast_to(z[:, None], rows).reshape(-1, bn)
+        q = qw_ref[r0:r1, :]
+        for j in range(NIB):
+            w = ((nibble(q, j).astype(jnp.float32) - z) * s).astype(cdt)
+            acc += jnp.dot(x_ref[j, :, r0:r1].astype(cdt), w,
+                           preferred_element_type=jnp.float32)
+    o_ref[...] = (acc + b_ref[...]).astype(o_ref.dtype)
+
+
+def _lane_block(n: int, bn: int) -> tuple[int, int]:
+    """(bn, n_pad): a lane-aligned column block dividing the padded N.  N
+    below one lane tile is one full-width block; other N pad to a lane
+    multiple (only odd test shapes do — model widths are lane multiples)."""
+    if n < LANE:
+        return n, n
+    n_pad = _round_up(n, LANE)
+    bn = max(LANE, min(bn, n_pad) // LANE * LANE)
+    while n_pad % bn:
+        bn -= LANE
+    return bn, n_pad
+
+
+def _k_chunks(kw: int, rows_per_group: int, bk: int) -> tuple:
+    """Static packed-row spans of the full-K loop: ``bk // 8`` rows rounded
+    to lane tiles (x plane slices start on a lane boundary), and never a
+    span that straddles a group boundary without starting on one."""
+    step = max(CHUNK_ROWS, bk // NIB // CHUNK_ROWS * CHUNK_ROWS)
+    if step % rows_per_group and rows_per_group % step:
+        step = rows_per_group
+    return tuple((r, min(r + step, kw)) for r in range(0, kw, step))
+
+
+def fused_full_k(x: jnp.ndarray, qweight: jnp.ndarray, scales: jnp.ndarray,
+                 qzeros: jnp.ndarray, bias: jnp.ndarray | None, *,
+                 group_size: int, bm: int, bn: int, bk: int, out_dtype,
+                 interpret: bool) -> jnp.ndarray:
+    """The served W4A16 kernel (decode GEMV and prefill matmul alike).
+    x: (M, K); qweight: (K//8, N) int32; returns (M, N) + bias."""
+    m, k = x.shape
+    n = scales.shape[1]
+    if k % NIB:
+        raise ValueError(f"K={k} not divisible by {NIB}: unservable by int4 "
+                         f"row packing (shape M={m}, K={k}, N={n})")
+    g = group_size if group_size > 0 else k
+    kw = k // NIB
+    bn, n_pad = _lane_block(n, bn)
+    z = packing.unpack_int4_cols(qzeros, n)        # (G, N) int8, lane-aligned
+    b = (jnp.zeros((1, n), jnp.float32) if bias is None
+         else bias.reshape(1, n).astype(jnp.float32))
+    if n_pad != n:
+        dn = n_pad - n
+        qweight = jnp.pad(qweight, ((0, 0), (0, dn)))
+        scales = jnp.pad(scales, ((0, 0), (0, dn)), constant_values=1.0)
+        z = jnp.pad(z, ((0, 0), (0, dn)))
+        b = jnp.pad(b, ((0, 0), (0, dn)))
+    bm = max(8, min(_round_up(bm, 8), _round_up(m, 8)))
+    m_pad = _round_up(m, bm)
+    if m_pad != m:
+        x = jnp.pad(x, ((0, m_pad - m), (0, 0)))
+    xd = x.reshape(m_pad, kw, NIB).transpose(2, 0, 1)     # (8, M, K//8)
+    gtot = scales.shape[0]
+    y = pl.pallas_call(
+        functools.partial(_kernel_full_k,
+                          chunks=_k_chunks(kw, g // NIB, bk),
+                          rows_per_group=g // NIB),
+        grid=(m_pad // bm, n_pad // bn),
+        in_specs=[
+            pl.BlockSpec((NIB, bm, kw), lambda mi, ni: (0, mi, 0)),
+            pl.BlockSpec((kw, bn), lambda mi, ni: (0, ni)),
+            pl.BlockSpec((gtot, bn), lambda mi, ni: (0, ni)),
+            pl.BlockSpec((gtot, bn), lambda mi, ni: (0, ni)),
+            pl.BlockSpec((1, bn), lambda mi, ni: (0, ni)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda mi, ni: (mi, ni)),
+        out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(xd, qweight, scales, z, b)
+    return y[:m, :n]
+
+
 # ------------------------------------------------------------------ dispatcher
 def _scale_block(bk, group_size):
     """Rows of the scales/zeros block covering bk K-rows."""
@@ -214,12 +349,18 @@ def gptq_matmul(x: jnp.ndarray, qweight: jnp.ndarray, scales: jnp.ndarray,
                 qzeros: jnp.ndarray, *, group_size: int,
                 strategy: KernelStrategy = OPT4GPTQ,
                 bm: int = 128, bn: int = 256, bk: int = 512,
-                out_dtype=None, interpret: bool = True) -> jnp.ndarray:
+                out_dtype=None, interpret: bool | None = None) -> jnp.ndarray:
     """Fused GPTQ matmul. x: (M, K). qweight: (K//8, N) int32 when
     ``strategy.packed_loads`` else (K, N) int8 (pre-expanded). Caller applies
     the act-order permutation to x (see ops.gptq_linear)."""
+    interpret = resolve_interpret(interpret)
     m, k = x.shape
     n = scales.shape[1]
+    if full_k_strategy(strategy):
+        return fused_full_k(x, qweight, scales, qzeros, None,
+                            group_size=group_size, bm=bm, bn=bn, bk=bk,
+                            out_dtype=out_dtype or x.dtype,
+                            interpret=interpret)
     g = group_size if group_size > 0 else k
     bm, bn, bk = resolve_block_sizes(m, k, n, group_size, bm, bn, bk)
     qweight, scales, qzeros, n_pad = pad_cols(qweight, scales, qzeros, n, bn)

@@ -1,8 +1,10 @@
 """Public jit'd wrappers dispatching Pallas kernels vs pure-jnp references.
 
-``use_pallas=False`` (default on this CPU container / for the dry-run) routes
-to the ref oracle — identical math and HBM traffic; ``use_pallas=True``
-invokes the Pallas kernels (interpret mode on CPU, compiled on real TPU).
+``use_pallas=False`` routes to the ref oracle — identical math and HBM
+traffic; ``use_pallas=True`` invokes the Pallas kernels, compiled or
+interpreted as ``interpret`` says (``None``: from the backend, see
+``kernels/lane.py``; ``KernelConfig.resolve()`` makes the choice for the
+model).
 
 The Pallas path has two lanes (DESIGN.md §7):
 
@@ -25,11 +27,12 @@ from repro.kernels import gptq_gemv as _gemv
 from repro.kernels import gptq_matmul as _gm
 from repro.kernels import ref as _ref
 from repro.kernels.gptq_gemv import GEMV_M_MAX
+from repro.kernels.lane import resolve_interpret
 
 
 def gptq_linear(ql: QuantizedLinear, x: jnp.ndarray, *,
                 strategy: KernelStrategy = OPT4GPTQ,
-                use_pallas: bool = False, interpret: bool = True,
+                use_pallas: bool = False, interpret: bool | None = None,
                 block_sizes: tuple[int, int, int] | str | None = None
                 ) -> jnp.ndarray:
     """y = x @ dequant(W) + bias  for x of shape (..., K)."""
@@ -41,6 +44,7 @@ def gptq_linear(ql: QuantizedLinear, x: jnp.ndarray, *,
     m = x2.shape[0]
 
     if use_pallas:
+        interpret = resolve_interpret(interpret)
         qw = (ql.qweight if strategy.packed_loads
               else packing.unpack_int4_rows(ql.qweight, k))   # VML-off: int8 2x
         if block_sizes == "auto":

@@ -6,6 +6,9 @@ non-contiguous fixed-size pages of a shared physical pool, addressed through a
 per-sequence block table — the vLLM PagedAttention layout the paper's serving
 substrate is built on, mapped to TPU idiom:
 
+* **Head-major pools** ``(Hkv, P, page_size, D)``: one kv head's page is a
+  ``(page_size, D)`` tile in the last two dimensions, which is what the TPU
+  (8, 128) block rule needs (int8 pools: page_size a multiple of 32).
 * **Grid (B, Hkv, n_pages)** with the block table and sequence lengths as
   *scalar-prefetch* operands: the K/V ``BlockSpec`` index maps read
   ``block_tables[b, p]`` so each program DMAs exactly one physical page into
@@ -24,8 +27,10 @@ substrate is built on, mapped to TPU idiom:
   the K/V pools hold int8 payloads and the kernel DMAs the page *plus its
   scales* into VMEM, rescaling inside the online-softmax loop: a floating-
   point copy of the KV cache never exists in HBM.  Scale pools are parallel
-  to the page pools — ``(P, page_size, Hkv)`` per-token or ``(P, Hkv)``
-  per-page symmetric scales (``serving/kv_quant.py``).
+  to the page pools — ``(Hkv, P, page_size)`` per-token or ``(Hkv, P)``
+  per-page symmetric scales (``serving/kv_quant.py``); the kernels view
+  them with trailing unit dimensions so a page's scales are one legal
+  block.
 
 * **Chunked paged prefill** (ISSUE 5) — ``paged_prefill`` runs the *whole
   suffix block* of a (possibly prefix-hit) prompt with online softmax
@@ -38,8 +43,8 @@ substrate is built on, mapped to TPU idiom:
   memory.  Both int8 scale granularities dequantize in VMEM here too.
 
 ``kernels/ref.py::paged_attention_ref`` / ``paged_prefill_ref`` are the jnp
-oracles; ``interpret=True`` (the default) runs these same kernels through
-the Pallas interpreter on CPU.
+oracles; ``interpret=None`` resolves the lane from the backend
+(``kernels/lane.py``): the Pallas interpreter on CPU, compiled on a TPU.
 """
 from __future__ import annotations
 
@@ -50,6 +55,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.lane import resolve_interpret
 
 
 def _sm_reset(m_ref, l_ref, acc_ref):
@@ -108,34 +115,43 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *, page_size, scale):
     b = pl.program_id(0)
     q = q_ref[0, 0].astype(jnp.float32)                  # (rep, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (page_size, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)               # (page_size, D)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (page_size, D)
+    v = v_ref[0, 0].astype(jnp.float32)                  # (page_size, D)
     _page_update(q, k, v, b, o_ref, m_ref, l_ref, acc_ref, len_ref,
                  page_size=page_size, scale=scale)
 
 
-def _dequant_page(k_ref, v_ref, ks_ref, vs_ref, *, per_page):
+def _dequant_page(k_ref, v_ref, ks_ref, vs_ref):
     """In-VMEM rescale of one int8 page: the page DMA brought the quantized
-    payload plus its scales; returns fp32 (page_size, D) k, v — no fp KV is
-    ever materialized in HBM."""
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (page_size, D) int8
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    if per_page:                                         # one scale per page
-        k = k * ks_ref[0, 0].astype(jnp.float32)
-        v = v * vs_ref[0, 0].astype(jnp.float32)
-    else:                                                # one per token
-        k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-        v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
+    payload plus its scales — a (page_size, 1) per-token column or a (1, 1)
+    per-page scalar, either broadcasting over (page_size, D).  Returns fp32
+    k, v; no fp KV is ever materialized in HBM."""
+    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0].astype(jnp.float32)
     return k, v
 
 
+def _scale_spec_and_views(k_scales, v_scales, page_size, index_map):
+    """BlockSpec + kernel views of the scale pools: (Hkv, P, page_size)
+    per-token scales become (Hkv, P, page_size, 1), (Hkv, P) per-page ones
+    (Hkv, P, 1, 1), so one page's scales are a block whose last two
+    dimensions are full."""
+    if k_scales.ndim == 2:                 # (Hkv, P): one scale per page
+        rows = 1
+        views = [k_scales[:, :, None, None], v_scales[:, :, None, None]]
+    else:                                  # (Hkv, P, page_size): per token
+        rows = page_size
+        views = [k_scales[..., None], v_scales[..., None]]
+    return pl.BlockSpec((1, 1, rows, 1), index_map), views
+
+
 def _kernel_quant(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, page_size, scale, per_page):
+                  m_ref, l_ref, acc_ref, *, page_size, scale):
     """Int8-KV decode variant: dequantization happens inside the online-
     softmax page loop (see ``_dequant_page``)."""
     b = pl.program_id(0)
     q = q_ref[0, 0].astype(jnp.float32)                  # (rep, D)
-    k, v = _dequant_page(k_ref, v_ref, ks_ref, vs_ref, per_page=per_page)
+    k, v = _dequant_page(k_ref, v_ref, ks_ref, vs_ref)
     _page_update(q, k, v, b, o_ref, m_ref, l_ref, acc_ref, len_ref,
                  page_size=page_size, scale=scale)
 
@@ -146,24 +162,25 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                     k_scales: jnp.ndarray | None = None,
                     v_scales: jnp.ndarray | None = None,
                     scale: float | None = None,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool | None = None) -> jnp.ndarray:
     """Single-token decode attention over a paged KV pool.
 
     q            : (B, H, D) — one query token per sequence.
-    k_pages/v_pages: (P, page_size, Hkv, D) physical page pools (int8 when
-                   ``k_scales``/``v_scales`` are given).
+    k_pages/v_pages: (Hkv, P, page_size, D) head-major physical page pools
+                   (int8 when ``k_scales``/``v_scales`` are given).
     block_tables : (B, max_pages) int32 — logical page i of sequence b lives
                    in physical page ``block_tables[b, i]``; padding entries
                    must point at a valid (e.g. null) page.
     lengths      : (B,) int32 — keys at logical positions < lengths[b] attend
                    (the just-written decode token included).
     k_scales/v_scales: optional symmetric dequant scales parallel to the
-                   pools — (P, page_size, Hkv) per-token or (P, Hkv)
+                   pools — (Hkv, P, page_size) per-token or (Hkv, P)
                    per-page; dequantization is fused into the page loop.
     Returns (B, H, D).
     """
+    interpret = resolve_interpret(interpret)
     b, h, d = q.shape
-    _, page_size, hkv, _ = k_pages.shape
+    hkv, _, page_size, _ = k_pages.shape
     assert h % hkv == 0, (h, hkv)
     if (k_scales is None) != (v_scales is None):
         raise ValueError("pass both k_scales and v_scales, or neither")
@@ -172,28 +189,25 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
     qg = q.reshape(b, hkv, rep, d)
+
+    def page_map(b, h, p, bt, ln):
+        return (h, bt[b, p], 0, 0)
+
     in_specs = [
         pl.BlockSpec((1, 1, rep, d), lambda b, h, p, bt, ln: (b, h, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, p, bt, ln: (bt[b, p], 0, h, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, p, bt, ln: (bt[b, p], 0, h, 0)),
+        pl.BlockSpec((1, 1, page_size, d), page_map),
+        pl.BlockSpec((1, 1, page_size, d), page_map),
     ]
     inputs = [qg, k_pages, v_pages]
     if k_scales is None:
         kernel = functools.partial(_kernel, page_size=page_size, scale=scale)
     else:
-        per_page = k_scales.ndim == 2          # (P, Hkv) vs (P, ps, Hkv)
-        if per_page:
-            scale_spec = pl.BlockSpec((1, 1),
-                                      lambda b, h, p, bt, ln: (bt[b, p], h))
-        else:
-            scale_spec = pl.BlockSpec((1, page_size, 1),
-                                      lambda b, h, p, bt, ln: (bt[b, p], 0, h))
+        scale_spec, views = _scale_spec_and_views(k_scales, v_scales,
+                                                  page_size, page_map)
         in_specs += [scale_spec, scale_spec]
-        inputs += [k_scales, v_scales]
+        inputs += views
         kernel = functools.partial(_kernel_quant, page_size=page_size,
-                                   scale=scale, per_page=per_page)
+                                   scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, max_pages),
@@ -258,20 +272,20 @@ def _prefill_kernel(bt_ref, st_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                     m_ref, l_ref, acc_ref, *, page_size, rep, scale):
     b = pl.program_id(0)
     q = q_ref[0, 0, 0].astype(jnp.float32)               # (chunk*rep, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (page_size, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (page_size, D)
+    v = v_ref[0, 0].astype(jnp.float32)
     _prefill_update(q, k, v, b, o_ref, m_ref, l_ref, acc_ref, st_ref, len_ref,
                     page_size=page_size, rep=rep, scale=scale)
 
 
 def _prefill_kernel_quant(bt_ref, st_ref, len_ref, q_ref, k_ref, v_ref,
                           ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                          page_size, rep, scale, per_page):
+                          page_size, rep, scale):
     """Int8-KV prefill variant: page payload + scales arrive in one DMA and
     the rescale happens inside the online-softmax page loop."""
     b = pl.program_id(0)
     q = q_ref[0, 0, 0].astype(jnp.float32)               # (chunk*rep, D)
-    k, v = _dequant_page(k_ref, v_ref, ks_ref, vs_ref, per_page=per_page)
+    k, v = _dequant_page(k_ref, v_ref, ks_ref, vs_ref)
     _prefill_update(q, k, v, b, o_ref, m_ref, l_ref, acc_ref, st_ref, len_ref,
                     page_size=page_size, rep=rep, scale=scale)
 
@@ -284,14 +298,14 @@ def paged_prefill(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                   k_scales: jnp.ndarray | None = None,
                   v_scales: jnp.ndarray | None = None,
                   scale: float | None = None, q_chunk: int = 128,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool | None = None) -> jnp.ndarray:
     """Chunked prefill attention over a paged KV pool (ISSUE 5 tentpole).
 
     q            : (B, S, H, D) — the suffix query block; query i of row b
                    sits at absolute position ``seq_start[b] + i`` (its KV
                    must already be written to the pool).
-    k_pages/v_pages: (P, page_size, Hkv, D) physical page pools (int8 when
-                   ``k_scales``/``v_scales`` are given).
+    k_pages/v_pages: (Hkv, P, page_size, D) head-major physical page pools
+                   (int8 when ``k_scales``/``v_scales`` are given).
     block_tables : (B, max_pages) int32 — padding entries must point at a
                    valid (e.g. null) page.
     seq_start    : (B,) int32 — prefix-hit length (0 on a cold prefill).
@@ -303,8 +317,9 @@ def paged_prefill(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     (q_chunk·rep, D)); S is padded up to a chunk multiple internally and the
     pad rows' outputs are sliced off.  Returns (B, S, H, D).
     """
+    interpret = resolve_interpret(interpret)
     b, s, h, d = q.shape
-    _, page_size, hkv, _ = k_pages.shape
+    hkv, _, page_size, _ = k_pages.shape
     assert h % hkv == 0, (h, hkv)
     if (k_scales is None) != (v_scales is None):
         raise ValueError("pass both k_scales and v_scales, or neither")
@@ -320,31 +335,26 @@ def paged_prefill(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     # is a kv head's rep query heads over one chunk of positions
     qg = q.reshape(b, nq, chunk, hkv, rep, d).transpose(0, 3, 1, 2, 4, 5)
     qg = qg.reshape(b, hkv, nq, chunk * rep, d)
+    def page_map(b, h, qc, p, bt, st, ln):
+        return (h, bt[b, p], 0, 0)
+
     in_specs = [
         pl.BlockSpec((1, 1, 1, chunk * rep, d),
                      lambda b, h, qc, p, bt, st, ln: (b, h, qc, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, qc, p, bt, st, ln: (bt[b, p], 0, h, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, qc, p, bt, st, ln: (bt[b, p], 0, h, 0)),
+        pl.BlockSpec((1, 1, page_size, d), page_map),
+        pl.BlockSpec((1, 1, page_size, d), page_map),
     ]
     inputs = [qg, k_pages, v_pages]
     if k_scales is None:
         kernel = functools.partial(_prefill_kernel, page_size=page_size,
                                    rep=rep, scale=scale)
     else:
-        per_page = k_scales.ndim == 2          # (P, Hkv) vs (P, ps, Hkv)
-        if per_page:
-            scale_spec = pl.BlockSpec(
-                (1, 1), lambda b, h, qc, p, bt, st, ln: (bt[b, p], h))
-        else:
-            scale_spec = pl.BlockSpec(
-                (1, page_size, 1),
-                lambda b, h, qc, p, bt, st, ln: (bt[b, p], 0, h))
+        scale_spec, views = _scale_spec_and_views(k_scales, v_scales,
+                                                  page_size, page_map)
         in_specs += [scale_spec, scale_spec]
-        inputs += [k_scales, v_scales]
+        inputs += views
         kernel = functools.partial(_prefill_kernel_quant, page_size=page_size,
-                                   rep=rep, scale=scale, per_page=per_page)
+                                   rep=rep, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, hkv, nq, max_pages),

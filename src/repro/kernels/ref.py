@@ -81,6 +81,16 @@ def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return out.astype(q.dtype)
 
 
+def _token_major_pools(k_pages, v_pages, k_scales, v_scales):
+    """Head-major (Hkv, P, ps, D) pools -> dequantized (P, ps, Hkv, D)."""
+    if (k_scales is None) != (v_scales is None):
+        raise ValueError("pass both k_scales and v_scales, or neither")
+    if k_scales is not None:
+        k_pages = kv_quant.dequantize(k_pages, k_scales, dtype=jnp.float32)
+        v_pages = kv_quant.dequantize(v_pages, v_scales, dtype=jnp.float32)
+    return k_pages.transpose(1, 2, 0, 3), v_pages.transpose(1, 2, 0, 3)
+
+
 def paged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                         v_pages: jnp.ndarray, block_tables: jnp.ndarray,
                         lengths: jnp.ndarray, *,
@@ -89,17 +99,15 @@ def paged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                         scale: float | None = None) -> jnp.ndarray:
     """Oracle for ``kernels/paged_attention.py``: gather every sequence's
     pages into a contiguous (B, max_pages*page_size, Hkv, D) view, then run
-    masked grouped attention.  q: (B, H, D); k/v_pages: (P, ps, Hkv, D);
-    block_tables: (B, max_pages) int32; lengths: (B,) int32. -> (B, H, D).
+    masked grouped attention.  q: (B, H, D); k/v_pages: (Hkv, P, ps, D)
+    head-major; block_tables: (B, max_pages) int32; lengths: (B,) int32.
+    -> (B, H, D).
 
-    ``k_scales``/``v_scales`` — (P, ps, Hkv) per-token or (P, Hkv) per-page
+    ``k_scales``/``v_scales`` — (Hkv, P, ps) per-token or (Hkv, P) per-page
     symmetric scales for int8 pools (``serving/kv_quant.py``): the oracle
     simply materializes the dequantized pools, which the kernel never does."""
-    if (k_scales is None) != (v_scales is None):
-        raise ValueError("pass both k_scales and v_scales, or neither")
-    if k_scales is not None:
-        k_pages = kv_quant.dequantize(k_pages, k_scales, dtype=jnp.float32)
-        v_pages = kv_quant.dequantize(v_pages, v_scales, dtype=jnp.float32)
+    k_pages, v_pages = _token_major_pools(k_pages, v_pages, k_scales,
+                                          v_scales)
     b, h, d = q.shape
     _, ps, hkv, _ = k_pages.shape
     rep = h // hkv
@@ -130,7 +138,8 @@ def paged_prefill_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     sequence's pages into a contiguous (B, max_pages*page_size, Hkv, D) view
     (densely dequantized when int8 — exactly the materialization the kernel
     exists to avoid), then run causally masked grouped attention over the
-    whole suffix block.
+    whole suffix block.  Pools are head-major (Hkv, P, ps, D), as for the
+    kernel.
 
     q: (B, S, H, D) — query i of row b sits at absolute position
     ``seq_start[b] + i``; ``lengths``: (B,) total valid keys per row
@@ -138,11 +147,8 @@ def paged_prefill_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     unwritten reserve pages.  Fully-masked query rows yield exact zeros,
     matching the kernel's zero-normalizer convention.  -> (B, S, H, D).
     """
-    if (k_scales is None) != (v_scales is None):
-        raise ValueError("pass both k_scales and v_scales, or neither")
-    if k_scales is not None:
-        k_pages = kv_quant.dequantize(k_pages, k_scales, dtype=jnp.float32)
-        v_pages = kv_quant.dequantize(v_pages, v_scales, dtype=jnp.float32)
+    k_pages, v_pages = _token_major_pools(k_pages, v_pages, k_scales,
+                                          v_scales)
     b, s, h, d = q.shape
     _, ps, hkv, _ = k_pages.shape
     rep = h // hkv

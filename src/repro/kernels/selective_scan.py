@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.lane import resolve_interpret
+
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
             y_ref, hout_ref, h_ref, *, bl):
@@ -34,14 +36,14 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
     # jax versions (`'int' object has no attribute 'shape'`).
     def step(j, h):
         row = (slice(0, 1), pl.ds(j, 1), slice(None))
-        xt = pl.load(x_ref, row)[0, 0].astype(jnp.float32)           # (bd,)
-        dt = jax.nn.softplus(pl.load(dt_ref, row)[0, 0].astype(jnp.float32))
-        bt = pl.load(b_ref, row)[0, 0].astype(jnp.float32)           # (S,)
-        ct = pl.load(c_ref, row)[0, 0].astype(jnp.float32)
+        xt = x_ref[row][0, 0].astype(jnp.float32)                    # (bd,)
+        dt = jax.nn.softplus(dt_ref[row][0, 0].astype(jnp.float32))
+        bt = b_ref[row][0, 0].astype(jnp.float32)                    # (S,)
+        ct = c_ref[row][0, 0].astype(jnp.float32)
         da = jnp.exp(dt[:, None] * a)                      # (bd, S)
         h = da * h + (dt * xt)[:, None] * bt[None, :]
         y = jnp.sum(h * ct[None, :], axis=1) + dvec * xt
-        pl.store(y_ref, row, y[None, None, :].astype(y_ref.dtype))
+        y_ref[row] = y[None, None, :].astype(y_ref.dtype)
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, bl, step, h_ref[...])
@@ -53,9 +55,10 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
 
 @functools.partial(jax.jit, static_argnames=("bd", "bl", "interpret"))
 def selective_scan(x, dt, a, b, c, d, h0=None, *, bd: int = 512,
-                   bl: int = 128, interpret: bool = True):
+                   bl: int = 128, interpret: bool | None = None):
     """x, dt: (B, L, Di); a: (Di, S); b, c: (B, L, S); d: (Di,);
     h0: (B, Di, S) or None. Returns (y (B, L, Di), h_last (B, Di, S))."""
+    interpret = resolve_interpret(interpret)
     bsz, length, di = x.shape
     s = a.shape[1]
     bd = min(bd, di)

@@ -1,6 +1,12 @@
-"""Serving driver: GPTQ-quantize a model and either run a synthetic request
-stream through the continuous-batching engine (offline mode, default) or
-expose it as an OpenAI-style HTTP service (``--serve``).
+"""Serving driver: build a 4-bit GPTQ model from a seed and either run a
+synthetic request stream through the continuous-batching engine (offline
+mode, default) or expose it as an OpenAI-style HTTP service (``--serve``).
+
+The weights are random: every projection is drawn as int4 at its quantized
+shape (``core/quantize_model.py::random_quantized_params``), so a
+published-width model loads without ever holding its dense copy.  The
+kernel lane follows the backend: compiled Pallas on a TPU, the Pallas
+interpreter on the CPU (``kernels/lane.py``).
 
   # offline throughput run
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3_4b --smoke \
@@ -42,7 +48,7 @@ def build_engine(args):
     from repro.configs import get_config, smoke_config
     from repro.core.gptq import GPTQConfig
     from repro.core.opt_strategies import get_strategy
-    from repro.core.quantize_model import quantize_params
+    from repro.core.quantize_model import random_quantized_params
     from repro.models import build_model, layers as L
     from repro.serving.api import EngineConfig
     from repro.serving.engine import Engine
@@ -51,11 +57,10 @@ def build_engine(args):
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
-    params = model.init(jax.random.key(0))
-    qparams = quantize_params(params, None, GPTQConfig(group_size=32))
+    qparams = random_quantized_params(
+        model, GPTQConfig(group_size=args.group_size), seed=args.seed)
     kern = L.KernelConfig(strategy=get_strategy(args.strategy),
-                          use_pallas=not args.no_pallas,
-                          block_sizes=(8, 64, 64))
+                          use_pallas=not args.no_pallas)
     tracer = Tracer() if args.trace_out else None
     spec = None
     if args.speculate != "off":
@@ -70,7 +75,9 @@ def build_engine(args):
         default_queue_timeout_s=args.queue_timeout,
         metrics=not args.no_metrics, tracer=tracer,
         speculation=spec, prefix_cache_path=args.prefix_cache,
-        mesh_shape=(tp,) if tp > 1 else None))
+        mesh_shape=(tp,) if tp > 1 else None, seed=args.seed,
+        page_pool_bytes=(None if args.page_pool_gib is None
+                         else int(args.page_pool_gib * 2 ** 30))))
     if tp > 1:
         log_event(args, "tensor_parallel", tp=tp,
                   devices=len(jax.devices()))
@@ -88,6 +95,8 @@ def export_trace(args, eng):
 
 
 def run_offline(args, cfg, eng):
+    import jax
+
     from repro.data.pipeline import sharegpt_stream
 
     stream = sharegpt_stream(args.requests, vocab_size=cfg.vocab_size,
@@ -136,9 +145,10 @@ def run_offline(args, cfg, eng):
             extra += (f", spec accept {s.spec_accepted}/{s.spec_proposed}"
                       f" ({s.acceptance_rate:.0%},"
                       f" {s.tokens_per_step:.2f} tok/step)")
-        print(f"[serve] {cfg.name} x {args.strategy} [{args.cache}]: "
-              f"{len(done)} reqs, {toks} tokens, {toks / dt:.2f} tok/s "
-              f"(interpret), p50 {lat[len(lat) // 2]:.2f}s{extra}")
+        print(f"[serve] {cfg.name} x {args.strategy} [{args.cache}] on "
+              f"{jax.devices()[0].platform}: {len(done)} reqs, {toks} "
+              f"tokens, {toks / dt:.2f} tok/s host wall clock, p50 "
+              f"{lat[len(lat) // 2]:.2f}s{extra}")
     if persist:
         log_event(args, "prefix_cache_saved", path=args.prefix_cache,
                   pages=saved)
@@ -170,7 +180,9 @@ def run_http(args, cfg, eng):
             export_trace(args, eng)
 
 
-def main(argv=None):
+def make_parser() -> argparse.ArgumentParser:
+    """The driver's command line — also how ``chip_smoke.py`` configures
+    ``build_engine``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_4b")
     ap.add_argument("--smoke", action="store_true")
@@ -184,6 +196,13 @@ def main(argv=None):
                     help="KV layout: fixed slots or PagedAttention block "
                          "tables (DESIGN.md §10)")
     ap.add_argument("--page-size", type=int, default=16)
+    ap.add_argument("--page-pool-gib", type=float, default=None,
+                    help="size the paged KV pool from this many GiB per "
+                         "device instead of slots x max_len")
+    ap.add_argument("--group-size", type=int, default=128,
+                    help="GPTQ quantization group along K")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random int4 weights and of sampling")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree (DESIGN.md §17): shard "
                          "GPTQ weights and the KV page pools across this "
@@ -233,8 +252,14 @@ def main(argv=None):
     ap.add_argument("--no-metrics", action="store_true",
                     help="disable the metrics registry (NullRegistry: "
                          "/metrics exposes nothing, EngineStats reads zero)")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None):
+    args = make_parser().parse_args(argv)
+
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     cfg, eng = build_engine(args)
     if args.serve:
         run_http(args, cfg, eng)

@@ -9,7 +9,8 @@ train_4k backward memory.
 
 Cache layouts (DESIGN.md §2/§10):
   full attention : slot — k/v (B, max_len, Hkv, D), write at seq_lens via
-                   scatter; or paged — k/v pools (pages, page_size, Hkv, D)
+                   scatter; or paged — head-major k/v pools (Hkv, pages,
+                   page_size, D)
                    addressed through a per-sequence device block table
                    (decode AND prefill run kernels/paged_attention.py —
                    no gathered KV copy exists anywhere on the paged path)
@@ -175,7 +176,8 @@ def _resolve_q_chunk(kernels, chunk: int, s: int, cfg, page_size: int) -> int:
     if qc == "auto":
         from repro.kernels import autotune as AT
         qc = AT.get_q_chunk(s, cfg.num_heads, cfg.num_kv_heads,
-                            cfg.head_dim, page_size)
+                            cfg.head_dim, page_size,
+                            interpret=kernels.resolve().interpret)
     return min(chunk, qc or 128)
 
 
@@ -217,9 +219,10 @@ def gqa_apply(p, x, *, cfg: ModelConfig, kernels=L.DEFAULT_KERNELS,
         # aliasing into the last table column's live page.
         assert block_tables is not None, "paged cache requires block_tables"
         assert window == 0 and num_sink == 0, "paged layout is full-attn only"
-        kp, vp = cache["k_pages"], cache["v_pages"]
+        interpret = kernels.resolve().interpret        # the kernel lane
+        kp, vp = cache["k_pages"], cache["v_pages"]   # (Hkv, P, ps, D)
         ksc, vsc = cache.get("k_scales"), cache.get("v_scales")
-        ps = kp.shape[1]
+        ps = kp.shape[2]
         tpos = seq_lens[:, None] + jnp.arange(s)[None, :]          # (B, S) abs
         # out-of-range logical pages (an overrunning sequence) fill with the
         # null page instead of aliasing into the last table column
@@ -230,24 +233,34 @@ def gqa_apply(p, x, *, cfg: ModelConfig, kernels=L.DEFAULT_KERNELS,
                               pages, 0)                            # null page
         offs = tpos % ps
         # one scatter per pool per layer-call: every new token's KV lands in
-        # its (page, offset) cell in a single batched write — quantize-on-
-        # write when the pool carries scale arrays (per-token granularity)
+        # its (page, offset) cell of every head in a single batched write —
+        # quantize-on-write when the pool carries scale arrays (per-token
+        # granularity).  The pools are head-major, so the written values
+        # move their head axis to the front: (B, S, Hkv, ...) -> (Hkv, B, S,
+        # ...)
+        def head_major(a):
+            return jnp.moveaxis(a, 2, 0)
+
         if ksc is not None:
             kq, kss = KQ.quantize(k, scale_dtype=ksc.dtype)
             vq, vss = KQ.quantize(v, scale_dtype=vsc.dtype)
-            kp = kp.at[pages, offs].set(kq)
-            vp = vp.at[pages, offs].set(vq)
-            ksc = ksc.at[pages, offs].set(kss)
-            vsc = vsc.at[pages, offs].set(vss)
+            kp = kp.at[:, pages, offs].set(head_major(kq))
+            vp = vp.at[:, pages, offs].set(head_major(vq))
+            ksc = ksc.at[:, pages, offs].set(head_major(kss))
+            vsc = vsc.at[:, pages, offs].set(head_major(vss))
         else:
-            kp = kp.at[pages, offs].set(k.astype(kp.dtype))
-            vp = vp.at[pages, offs].set(v.astype(vp.dtype))
+            kp = kp.at[:, pages, offs].set(head_major(k).astype(kp.dtype))
+            vp = vp.at[:, pages, offs].set(head_major(v).astype(vp.dtype))
         if s == 1:
-            fn = (PA.paged_attention
-                  if kernels.paged_attention_impl == "kernel"
-                  else KR.paged_attention_ref)
-            out = fn(q[:, 0], kp, vp, block_tables, seq_lens + 1,
-                     k_scales=ksc, v_scales=vsc)[:, None]
+            if kernels.paged_attention_impl == "kernel":
+                out = PA.paged_attention(
+                    q[:, 0], kp, vp, block_tables, seq_lens + 1,
+                    k_scales=ksc, v_scales=vsc, interpret=interpret)
+            else:
+                out = KR.paged_attention_ref(
+                    q[:, 0], kp, vp, block_tables, seq_lens + 1,
+                    k_scales=ksc, v_scales=vsc)
+            out = out[:, None]
         else:
             wl = (write_lens if write_lens is not None
                   else jnp.full((b,), s, jnp.int32))
@@ -256,7 +269,8 @@ def gqa_apply(p, x, *, cfg: ModelConfig, kernels=L.DEFAULT_KERNELS,
                                        seq_lens + wl, k_scales=ksc,
                                        v_scales=vsc,
                                        q_chunk=_resolve_q_chunk(
-                                           kernels, chunk, s, cfg, ps))
+                                           kernels, chunk, s, cfg, ps),
+                                       interpret=interpret)
             else:
                 out = KR.paged_prefill_ref(q, kp, vp, block_tables, seq_lens,
                                            seq_lens + wl, k_scales=ksc,

@@ -123,15 +123,16 @@ def block_cache_init(cfg: ModelConfig, kind: BlockKind, batch: int,
 
 def block_paged_cache_init(cfg: ModelConfig, kind: BlockKind, num_pages: int,
                            page_size: int, dtype=jnp.bfloat16, kv_quant=None):
-    """Paged-layout cache for one block: (num_pages + 1, page_size, Hkv, D)
-    physical pools (page 0 is the null page — see serving/kv_cache.py), plus
-    (num_pages + 1, page_size, Hkv) per-token scale pools when ``kv_quant``
-    stores int8.  Only homogeneous full-attention stacks support paging."""
+    """Paged-layout cache for one block: head-major (Hkv, num_pages + 1,
+    page_size, D) physical pools (page 0 is the null page — see
+    serving/kv_cache.py), plus (Hkv, num_pages + 1, page_size) per-token
+    scale pools when ``kv_quant`` stores int8.  Only homogeneous
+    full-attention stacks support paging."""
     if kind.attn != "full" or kind.ssm:
         raise ValueError(
             f"paged cache layout supports full-attention blocks only, got "
             f"attn={kind.attn!r} ssm={kind.ssm}")
-    shape = (num_pages + 1, page_size, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_kv_heads, num_pages + 1, page_size, cfg.head_dim)
     if kv_quant is not None and kv_quant.quantized:
         if kv_quant.granularity != "token":
             raise ValueError(

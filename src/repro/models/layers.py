@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.core.gptq import QuantizedLinear, accumulate_hessian
 from repro.core.opt_strategies import KernelStrategy, OPT4GPTQ
+from repro.kernels import lane
 from repro.kernels import ops as kops
 
 
@@ -191,10 +192,17 @@ class KernelConfig:
     persisted to JSON).  ``cache_layout`` selects the serving cache layout
     (``Engine(cache=...)`` defaults to it); ``paged_attention_impl`` /
     ``paged_prefill_impl`` pick the paged decode / prefill hot paths —
-    ``"kernel"`` (the Pallas kernels, interpret-mode on CPU) or ``"ref"``
-    (the jnp gather oracles in ``kernels/ref.py``, which materialize a
-    contiguous KV copy — debugging and the bench's gather-vs-kernel
-    comparison only).
+    ``"kernel"`` (the Pallas kernels) or ``"ref"`` (the jnp gather oracles
+    in ``kernels/ref.py``, which materialize a contiguous KV copy —
+    debugging and the bench's gather-vs-kernel comparison only).
+
+    The kernel lane is one decision, made from the backend by ``resolve()``
+    (``kernels/lane.py``), which the engine calls once when it is built:
+    ``interpret`` is True only on the CPU backend, and ``use_pallas`` left
+    at ``None`` means the Pallas GPTQ kernels everywhere but the CPU, where
+    the jnp reference keeps the test suite fast.  ``use_pallas=False`` is
+    the reference lane on purpose (the oracle a chip run is checked
+    against), never a fallback.
 
     ``q_chunk`` bounds the query rows per grid step of the chunked
     ``paged_prefill`` kernel (the VMEM query tile is (q_chunk·rep, D)).
@@ -202,7 +210,8 @@ class KernelConfig:
     cache (co-tuned with the engine's step token budget); a concrete value
     must be a positive multiple of the 128-wide TPU lane."""
     strategy: KernelStrategy = OPT4GPTQ
-    use_pallas: bool = False          # False: jnp ref path (CPU / dry-run)
+    use_pallas: bool | None = None    # None: Pallas off the CPU (resolve())
+    interpret: bool | None = None     # None: interpret on the CPU only
     block_sizes: tuple[int, int, int] | str | None = None
     cache_layout: str = CacheLayout.SLOT
     paged_attention_impl: str = "kernel"
@@ -218,6 +227,21 @@ class KernelConfig:
             raise ValueError(
                 f"q_chunk must be a positive multiple of the {LANE}-wide "
                 f"lane (or 'auto'), got {qc!r}")
+
+    def resolve(self) -> "KernelConfig":
+        """This config with the kernel lane fixed from the backend.  A TPU
+        never runs a kernel interpreted, so ``interpret=True`` off the CPU
+        is an error."""
+        cpu = lane.default_interpret()
+        interpret = cpu if self.interpret is None else self.interpret
+        if interpret and not cpu:
+            raise ValueError(
+                f"interpret=True on the {jax.default_backend()!r} backend: "
+                f"Pallas kernels run interpreted on the CPU only")
+        use_pallas = (not interpret if self.use_pallas is None
+                      else self.use_pallas)
+        return dataclasses.replace(self, interpret=interpret,
+                                   use_pallas=use_pallas)
 
 
 DEFAULT_KERNELS = KernelConfig()
@@ -240,8 +264,10 @@ def linear(p, x: jnp.ndarray, *, name: str = "",
         _CAPTURE.add(qualified(name), x)
     w = p["w"]
     if isinstance(w, QuantizedLinear):
+        kernels = kernels.resolve()      # idempotent; the engine's is resolved
         y = kops.gptq_linear(w, x, strategy=kernels.strategy,
                              use_pallas=kernels.use_pallas,
+                             interpret=kernels.interpret,
                              block_sizes=kernels.block_sizes)
     else:
         y = jnp.dot(x, w.astype(x.dtype))

@@ -146,8 +146,8 @@ class LM:
         pools addressed by a shared block table.  Requires a homogeneous
         full-attention stack with no meta tokens.  ``kv_quant`` adds int8
         pools with parallel per-token scale pools (DESIGN.md §12).  The
-        dtype default mirrors ``init_cache``; the serving engine always
-        passes ``kv_cache.DEFAULT_CACHE_DTYPE`` explicitly."""
+        dtype default mirrors ``init_cache``; the serving engine passes
+        its cache dtype (the model's dtype unless configured) explicitly."""
         cfg = self.cfg
         if cfg.meta_tokens:
             raise ValueError("paged cache layout does not support meta tokens")
@@ -193,7 +193,7 @@ class LM:
     # ----------------------------------------------------------- serving steps
     def forward_chunks(self, params, tokens, chunk_lens, cache, seq_lens, *,
                        kernels=L.DEFAULT_KERNELS, block_tables=None,
-                       extra=None):
+                       extra=None, logit_idx=None):
         """Unified serving forward (ISSUE 10, DESIGN.md §18): every row is
         one (chunk_start=seq_lens, chunk_len) span of its sequence — decode
         is a 1-token chunk, chunked prefill a budget-sized chunk, spec-verify
@@ -204,16 +204,22 @@ class LM:
                      positions' cache writes are null-routed (paged) or
                      dropped (slot), and their keys are masked out of every
                      row's attention window.
+        logit_idx  : optional (B, W) int32 chunk positions whose logits are
+                     wanted — the head then runs on W rows per sequence, not
+                     C (a (B, C, V) fp32 block at a 150k vocab is gigabytes).
         Row positions start at the absolute offset ``seq_lens``.  Returns
-        (logits (B, C, V) fp32, new_cache); the caller advances seq_lens.
+        (logits (B, C or W, V) fp32, new_cache); the caller advances
+        seq_lens.
         """
         batch = {"tokens": tokens}
         if extra:
             batch.update(extra)
-        logits, cache, _ = self.apply(
+        x, cache, _ = self.hidden(
             params, batch, kernels=kernels, cache=cache, seq_lens=seq_lens,
             mode="decode", block_tables=block_tables, write_lens=chunk_lens)
-        return logits, cache
+        if logit_idx is not None:
+            x = jnp.take_along_axis(x, logit_idx[:, :, None], axis=1)
+        return self._logits(params, x), cache
 
     def prefill(self, params, batch, cache, seq_lens, *,
                 kernels=L.DEFAULT_KERNELS, true_lengths=None,

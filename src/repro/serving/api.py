@@ -82,7 +82,7 @@ class EngineConfig:
     cache: str | None = None          # None -> kernels.cache_layout
     page_size: int = 16
     num_pages: int | None = None      # None -> batch_slots * ceil(max_len/page)
-    cache_dtype: object = None        # None -> kv_cache.DEFAULT_CACHE_DTYPE
+    cache_dtype: object = None        # None -> the model's dtype
     seed: int = 0
     # KV quantization (DESIGN.md §12): None, a KVQuantConfig, or a dtype
     # string shorthand ("int8" / "bf16" / "fp32" — normalized to a config)

@@ -251,7 +251,9 @@ class Engine:
         self.config = config
         self.model = model
         self.params = params
-        self.kernels = config.kernels
+        # the kernel lane (compiled / interpreted, Pallas / reference) is
+        # decided here, once, from the backend — kernels/lane.py
+        self.kernels = config.kernels.resolve()
         self.eos_id = config.eos_id
         self.sched = Scheduler()
         self.rng = jax.random.key(config.seed)
@@ -275,7 +277,9 @@ class Engine:
         elif config.cache_dtype is not None:
             cache_dtype = config.cache_dtype
         else:
-            cache_dtype = KV.DEFAULT_CACHE_DTYPE
+            # the KV pools store what the model computes in (bf16 for a
+            # served model; smoke configs compute in fp32)
+            cache_dtype = jnp.dtype(model.cfg.dtype)
         self.kv_quant = kvq
         # what the cache payloads are stored as (int8 when quantized)
         self.cache_dtype = jnp.dtype(jnp.int8) if kvq is not None \
@@ -340,15 +344,13 @@ class Engine:
                 head_dim=cfg.head_dim, dtype=cache_dtype,
                 max_seqs=batch_slots, max_pages=max_pages, alloc_pools=False,
                 kv_quant=kvq)
-            # raises for stacks paging can't serve (SSM/SWA/MLA/meta tokens)
-            self.cache = model.init_paged_cache(num_pages, page_size,
-                                                dtype=cache_dtype,
-                                                kv_quant=kvq)
+            # raises for stacks paging can't serve (SSM/SWA/MLA/meta tokens);
+            # under TP the pools are born head-sharded and the GPTQ weights
+            # are sharded here; page *ids* stay global, so the PagedCache
+            # bookkeeping above (free lists, refcounts, COW, prefix index)
+            # is unchanged
+            self.cache = self.paged_cache_like(num_pages)
             if self._tp_ctx is not None:
-                # head-shard the pools and the GPTQ weights; page *ids*
-                # stay global, so the PagedCache bookkeeping above (free
-                # lists, refcounts, COW, prefix index) is unchanged
-                self.cache = PL.shard_cache(self._tp_ctx, self.cache)
                 self.params = PL.shard_params(self._tp_ctx, self.params)
             # per-device pool accounting for the device-labeled gauges
             self.metrics.configure_devices(
@@ -473,9 +475,6 @@ class Engine:
             tokens = jax.lax.dynamic_update_slice(
                 tokens, jnp.where(dmask, drafts, span), (0, 1))
         wl = jnp.where(live, chunk_lens, 0)
-        logits, cache = model.forward_chunks(
-            params, tokens, wl, cache, seq_lens, kernels=kernels,
-            block_tables=block_tables)
         # verify window: positions [start, start+k] score the k drafts + the
         # bonus.  start = chunk_lens-1 for draft-free rows (the last real
         # position — its argmax/sample is the next token), 0 for verify rows
@@ -483,7 +482,10 @@ class Engine:
         idx = jnp.clip(
             start[:, None] + jnp.arange(k + 1, dtype=jnp.int32)[None, :],
             0, c - 1)
-        window = jnp.take_along_axis(logits, idx[:, :, None], axis=1)
+        # only the window's logits are computed: (B, k+1, V), never (B, C, V)
+        window, cache = model.forward_chunks(
+            params, tokens, wl, cache, seq_lens, kernels=kernels,
+            block_tables=block_tables, logit_idx=idx)
         n_acc, emitted = accept_speculative(
             window, drafts, draft_lens, keys, greedy=greedy, temps=temps,
             top_ks=top_ks, top_ps=top_ps, draft_probs=draft_probs,
@@ -500,6 +502,38 @@ class Engine:
         packed = jnp.concatenate([n_emit[:, None], emitted],
                                  axis=1).astype(jnp.int32)
         return packed, cache, seq_lens
+
+    # ------------------------------------------------- cache trees, probes
+    def paged_cache_like(self, num_pages: int):
+        """A fresh paged cache tree laid out like this engine's (dtype, KV
+        quantization, tensor-parallel sharding) with ``num_pages`` pages:
+        the engine's own pool, or scratch space for ``forward_probe``."""
+        init = functools.partial(
+            self.model.init_paged_cache, num_pages, self.pc.page_size,
+            dtype=self.cache_dtype, kv_quant=self.kv_quant)
+        if self._tp_ctx is not None:
+            return PL.init_cache(self._tp_ctx, init)
+        return init()
+
+    def forward_probe(self, kernels=None):
+        """Jitted ``model.forward_chunks`` over this engine's model and
+        parameter layout (shard_map'd under tensor parallelism), with
+        ``kernels`` as the lane (default: the engine's) — what parity checks
+        run a prefill and a decode through.  Call it as ``fn(params, tokens,
+        chunk_lens, cache, seq_lens, block_tables, logit_idx)``; it returns
+        ``(logits, cache)``."""
+        kernels = (self.kernels if kernels is None else kernels).resolve()
+        if self._tp_ctx is not None:
+            return jax.jit(PL.tp_wrap_forward(self._tp_ctx, kernels))
+
+        def fwd(params, tokens, chunk_lens, cache, seq_lens, block_tables,
+                logit_idx):
+            return self.model.forward_chunks(
+                params, tokens, chunk_lens, cache, seq_lens,
+                kernels=kernels, block_tables=block_tables,
+                logit_idx=logit_idx)
+
+        return jax.jit(fwd)
 
     @staticmethod
     def _prefill_impl(model, kernels, params, tokens, length, cache, seq_lens):
@@ -768,18 +802,19 @@ class Engine:
     # --------------------------------------------- paged admission/preemption
     def _gather_pages(self, page_ids: list[int]):
         """Host copies of the named physical pages from the engine's model
-        cache tree (page axis 1 in every pool/scale leaf) — the payload
+        cache tree (``KV.PAGE_AXIS`` in every pool/scale leaf) — the payload
         mover ``PagedCache.offload`` uses under ``alloc_pools=False``."""
         idx = np.asarray(page_ids, np.int32)
-        return jax.tree_util.tree_map(lambda a: np.asarray(a[:, idx]),
-                                      self.cache)
+        return jax.tree_util.tree_map(
+            lambda a: np.asarray(jnp.take(a, idx, axis=KV.PAGE_AXIS)),
+            self.cache)
 
     def _scatter_pages(self, page_ids: list[int], payload):
         """Write host pages back into the model cache tree at freshly
         allocated physical page ids (restore counterpart)."""
         idx = jnp.asarray(page_ids, jnp.int32)
         self.cache = jax.tree_util.tree_map(
-            lambda a, h: a.at[:, idx].set(jnp.asarray(h, a.dtype)),
+            lambda a, h: a.at[:, :, idx].set(jnp.asarray(h, a.dtype)),
             self.cache, payload)
 
     # ----------------------------------- prefix-cache persistence (§16)
@@ -847,7 +882,8 @@ class Engine:
         col = {k: i for i, k in enumerate(keys)}
         cols = [col[k] for k, _ in adopted]
         dest = [p for _, p in adopted]
-        sub = jax.tree_util.tree_map(lambda a: a[:, cols], payload)
+        sub = jax.tree_util.tree_map(
+            lambda a: np.take(a, cols, axis=KV.PAGE_AXIS), payload)
         self._scatter_pages(dest, sub)
         return len(adopted)
 

@@ -48,12 +48,15 @@ import numpy as np
 
 from repro.serving import kv_quant as KQ
 
-# The single source of the serving cache dtype: SlotCache, PagedCache and
-# Engine all default to this (the seed had SlotCache default to bfloat16
-# while Engine passed float32 — two defaults, one of them dead).
+# Default dtype of a standalone SlotCache / PagedCache.  The engine passes
+# its own: the model's dtype unless EngineConfig.cache_dtype says otherwise.
 DEFAULT_CACHE_DTYPE = jnp.float32
 
 NULL_PAGE = 0
+# Pools are head-major, (layers, Hkv, pages, page_size, D) — scale pools
+# (layers, Hkv, pages[, page_size]) — so one kv head's page is a
+# (page_size, D) tile for the paged kernels; physical pages index this axis.
+PAGE_AXIS = 2
 
 
 def prefix_hash_seed(quant_tag: tuple, page_size: int) -> int:
@@ -109,7 +112,7 @@ class OffloadedSeq:
     """Host-memory checkpoint of a preempted sequence (DESIGN.md §14).
 
     ``payload`` is a host copy of the sequence's *private* pages (logical
-    pages ``[shared_pages, pages_needed(length))`` — page axis 1 in every
+    pages ``[shared_pages, pages_needed(length))`` — ``PAGE_AXIS`` in every
     leaf, scale pools riding along).  The leading ``shared_pages`` full
     prefix pages were shared (refcount > 1) at offload time and were
     released without copying: their payload stays live on device under the
@@ -118,7 +121,7 @@ class OffloadedSeq:
     seq_id: int
     length: int                 # context tokens the snapshot covers
     shared_pages: int           # leading prefix pages released, not copied
-    payload: Any                # host pytree, page axis 1 (None when empty)
+    payload: Any                # host pytree, PAGE_AXIS (None when empty)
     n_payload_pages: int
     nbytes: int                 # host bytes held by ``payload``
 
@@ -140,7 +143,7 @@ class RestoredSeq:
 class PagedCache:
     """Block-table KV pool with a device-resident block table.
 
-    k_pages/v_pages: (n_layers, num_pages + 1, page_size, Hkv, D) pools.
+    k_pages/v_pages: (n_layers, Hkv, num_pages + 1, page_size, D) pools.
     block_tables   : (max_seqs, max_pages) int32 device array; row ``r`` maps
                      sequence-in-row-r logical page ``i`` to a physical page.
     Host bookkeeping (free list, refcounts, per-seq tables, prefix hashes)
@@ -155,6 +158,8 @@ class PagedCache:
     dtype: object = None            # None -> DEFAULT_CACHE_DTYPE
     max_seqs: int = 0               # 0 -> num_pages (every seq needs >=1 page)
     max_pages: int = 0              # block-table width; 0 -> num_pages
+                                    # (the engine sizes both from its slots
+                                    # and max_len / page_size)
     alloc_pools: bool = True        # False: bookkeeping only — the engine
                                     # stores page payloads in the model cache
                                     # tree (init_paged_cache), not here
@@ -168,8 +173,8 @@ class PagedCache:
         self.dtype = jnp.dtype(jnp.int8) if quantized else self.compute_dtype
         self.max_seqs = self.max_seqs or self.num_pages
         self.max_pages = self.max_pages or self.num_pages
-        shape = (self.n_layers, self.num_pages + 1, self.page_size,
-                 self.kv_heads, self.head_dim)
+        shape = (self.n_layers, self.kv_heads, self.num_pages + 1,
+                 self.page_size, self.head_dim)
         self.k_scales = self.v_scales = None
         if self.alloc_pools:
             self.k_pages = jnp.zeros(shape, self.dtype)
@@ -423,28 +428,29 @@ class PagedCache:
     # ------------------------------------------------------- offload / restore
     def _gather_pages_local(self, page_ids):
         """Default payload gather for ``alloc_pools=True``: host copies of
-        the named physical pages from this cache's own pools (page axis 1),
-        scale pools included."""
+        the named physical pages from this cache's own pools
+        (``PAGE_AXIS``), scale pools included."""
         self._require_pools()
         idx = np.asarray(page_ids, np.int32)
         tree = {"k_pages": self.k_pages, "v_pages": self.v_pages}
         if self.k_scales is not None:
             tree.update(k_scales=self.k_scales, v_scales=self.v_scales)
-        return jax.tree_util.tree_map(lambda a: np.asarray(a[:, idx]), tree)
+        return jax.tree_util.tree_map(
+            lambda a: np.asarray(jnp.take(a, idx, axis=PAGE_AXIS)), tree)
 
     def _scatter_pages_local(self, page_ids, payload):
         """Default payload scatter: write host pages back into this cache's
         pools at the (freshly allocated) physical page ids."""
         self._require_pools()
         idx = jnp.asarray(page_ids, jnp.int32)
-        self.k_pages = self.k_pages.at[:, idx].set(
+        self.k_pages = self.k_pages.at[:, :, idx].set(
             jnp.asarray(payload["k_pages"]))
-        self.v_pages = self.v_pages.at[:, idx].set(
+        self.v_pages = self.v_pages.at[:, :, idx].set(
             jnp.asarray(payload["v_pages"]))
         if self.k_scales is not None:
-            self.k_scales = self.k_scales.at[:, idx].set(
+            self.k_scales = self.k_scales.at[:, :, idx].set(
                 jnp.asarray(payload["k_scales"]))
-            self.v_scales = self.v_scales.at[:, idx].set(
+            self.v_scales = self.v_scales.at[:, :, idx].set(
                 jnp.asarray(payload["v_scales"]))
 
     def offload(self, seq_id: int,
@@ -457,7 +463,7 @@ class PagedCache:
         live under the donor's refcount and restore re-shares (or, if the
         donor evicted, recomputes) them.  Private pages covering the rest
         of ``[0, length)`` are copied to host via ``gather(page_ids)``
-        (page axis 1; the engine passes a gatherer over its model cache
+        (``PAGE_AXIS``; the engine passes a gatherer over its model cache
         tree, ``alloc_pools=True`` caches copy their own pools).  Reserve
         pages past the written extent hold no data and are just released.
         The block-table row, free list and prefix index are left exactly as
@@ -526,7 +532,7 @@ class PagedCache:
             dest = self.tables[seq_id][start:used]
             off = start - rec.shared_pages
             payload = jax.tree_util.tree_map(
-                lambda a: a[:, off:off + len(dest)], rec.payload)
+                lambda a: a[:, :, off:off + len(dest)], rec.payload)
             scatter = (scatter if scatter is not None
                        else self._scatter_pages_local)
             scatter(list(dest), payload)
@@ -571,18 +577,18 @@ class PagedCache:
                         raise RuntimeError(
                             "page pool exhausted during copy-on-write")
                     q = self.free_list.pop()
-                    self.k_pages = self.k_pages.at[:, q].set(
-                        self.k_pages[:, p])
-                    self.v_pages = self.v_pages.at[:, q].set(
-                        self.v_pages[:, p])
+                    self.k_pages = self.k_pages.at[:, :, q].set(
+                        self.k_pages[:, :, p])
+                    self.v_pages = self.v_pages.at[:, :, q].set(
+                        self.v_pages[:, :, p])
                     if self.k_scales is not None:
                         # scales travel with their pages: a COW'd payload
                         # dequantized against the donor's scales would be
                         # silently wrong after the follower rewrites either
-                        self.k_scales = self.k_scales.at[:, q].set(
-                            self.k_scales[:, p])
-                        self.v_scales = self.v_scales.at[:, q].set(
-                            self.v_scales[:, p])
+                        self.k_scales = self.k_scales.at[:, :, q].set(
+                            self.k_scales[:, :, p])
+                        self.v_scales = self.v_scales.at[:, :, q].set(
+                            self.v_scales[:, :, p])
                     self.refcount[p] -= 1
                     self.refcount[q] += 1
                     table[li] = q
@@ -610,28 +616,33 @@ class PagedCache:
         table = self.tables[seq_id]
         length = self.lengths[seq_id]
         lsel = jnp.asarray(layers, jnp.int32)
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
+        k = jnp.swapaxes(k.astype(jnp.float32), 1, 2)     # (L, Hkv, n, D)
+        v = jnp.swapaxes(v.astype(jnp.float32), 1, 2)
         for li in range(start // ps, (start + n - 1) // ps + 1):
             p = table[li]
             lo = li * ps
             valid = max(0, min(length, lo + ps) - lo)
-            kf = KQ.dequantize(self.k_pages[lsel, p], self.k_scales[lsel, p])
-            vf = KQ.dequantize(self.v_pages[lsel, p], self.v_scales[lsel, p])
+            # (L, Hkv, ps, D) payloads under (L, Hkv) page scales
+            kf = KQ.dequantize(self.k_pages[lsel][:, :, p],
+                               self.k_scales[lsel][:, :, p])
+            vf = KQ.dequantize(self.v_pages[lsel][:, :, p],
+                               self.v_scales[lsel][:, :, p])
             a, bnd = max(start, lo), min(start + n, lo + ps)
-            kf = kf.at[:, a - lo:bnd - lo].set(k[:, a - start:bnd - start])
-            vf = vf.at[:, a - lo:bnd - lo].set(v[:, a - start:bnd - start])
+            kf = kf.at[:, :, a - lo:bnd - lo].set(
+                k[:, :, a - start:bnd - start])
+            vf = vf.at[:, :, a - lo:bnd - lo].set(
+                v[:, :, a - start:bnd - start])
             # zero positions past the valid extent: stale payloads from a
             # recycled page must not inflate the page's amax
-            mask = (jnp.arange(ps) < valid)[None, :, None, None]
-            kq, ks = KQ.quantize(kf * mask, axes=(1, 3),
+            mask = (jnp.arange(ps) < valid)[None, None, :, None]
+            kq, ks = KQ.quantize(kf * mask, axes=(2, 3),
                                  scale_dtype=self.k_scales.dtype)
-            vq, vs = KQ.quantize(vf * mask, axes=(1, 3),
+            vq, vs = KQ.quantize(vf * mask, axes=(2, 3),
                                  scale_dtype=self.v_scales.dtype)
-            self.k_pages = self.k_pages.at[lsel, p].set(kq)
-            self.v_pages = self.v_pages.at[lsel, p].set(vq)
-            self.k_scales = self.k_scales.at[lsel, p].set(ks)
-            self.v_scales = self.v_scales.at[lsel, p].set(vs)
+            self.k_pages = self.k_pages.at[lsel, :, p].set(kq)
+            self.v_pages = self.v_pages.at[lsel, :, p].set(vq)
+            self.k_scales = self.k_scales.at[lsel, :, p].set(ks)
+            self.v_scales = self.v_scales.at[lsel, :, p].set(vs)
 
     def write_tokens(self, seq_id: int, layer: int, start: int,
                      k: jnp.ndarray, v: jnp.ndarray):
@@ -653,17 +664,19 @@ class PagedCache:
         pos = np.arange(start, start + n)
         pages = jnp.asarray(table[pos // self.page_size])
         offs = jnp.asarray(pos % self.page_size)
+        # (layer, :, pages, offs) selects an (n, Hkv[, D]) block: the
+        # advanced indices' axis leads, matching k/v and their scales
         if self.quantized:
             kq, ks = KQ.quantize(k, scale_dtype=self.k_scales.dtype)
             vq, vs = KQ.quantize(v, scale_dtype=self.v_scales.dtype)
-            self.k_pages = self.k_pages.at[layer, pages, offs].set(kq)
-            self.v_pages = self.v_pages.at[layer, pages, offs].set(vq)
-            self.k_scales = self.k_scales.at[layer, pages, offs].set(ks)
-            self.v_scales = self.v_scales.at[layer, pages, offs].set(vs)
+            self.k_pages = self.k_pages.at[layer, :, pages, offs].set(kq)
+            self.v_pages = self.v_pages.at[layer, :, pages, offs].set(vq)
+            self.k_scales = self.k_scales.at[layer, :, pages, offs].set(ks)
+            self.v_scales = self.v_scales.at[layer, :, pages, offs].set(vs)
         else:
-            self.k_pages = self.k_pages.at[layer, pages, offs].set(
+            self.k_pages = self.k_pages.at[layer, :, pages, offs].set(
                 k.astype(self.dtype))
-            self.v_pages = self.v_pages.at[layer, pages, offs].set(
+            self.v_pages = self.v_pages.at[layer, :, pages, offs].set(
                 v.astype(self.dtype))
 
     def write_prefill(self, seq_id: int, start: int,
@@ -680,18 +693,24 @@ class PagedCache:
         pos = np.arange(start, start + n)
         pages = jnp.asarray(table[pos // self.page_size])
         offs = jnp.asarray(pos % self.page_size)
+        # (:, :, pages, offs) selects (n_layers, Hkv, n[, D]): token axis
+        # after the head axis
         if self.quantized:
             kq, ks = KQ.quantize(k, scale_dtype=self.k_scales.dtype)
             vq, vs = KQ.quantize(v, scale_dtype=self.v_scales.dtype)
-            self.k_pages = self.k_pages.at[:, pages, offs].set(kq)
-            self.v_pages = self.v_pages.at[:, pages, offs].set(vq)
-            self.k_scales = self.k_scales.at[:, pages, offs].set(ks)
-            self.v_scales = self.v_scales.at[:, pages, offs].set(vs)
+            self.k_pages = self.k_pages.at[:, :, pages, offs].set(
+                jnp.swapaxes(kq, 1, 2))
+            self.v_pages = self.v_pages.at[:, :, pages, offs].set(
+                jnp.swapaxes(vq, 1, 2))
+            self.k_scales = self.k_scales.at[:, :, pages, offs].set(
+                jnp.swapaxes(ks, 1, 2))
+            self.v_scales = self.v_scales.at[:, :, pages, offs].set(
+                jnp.swapaxes(vs, 1, 2))
         else:
-            self.k_pages = self.k_pages.at[:, pages, offs].set(
-                k.astype(self.dtype))
-            self.v_pages = self.v_pages.at[:, pages, offs].set(
-                v.astype(self.dtype))
+            self.k_pages = self.k_pages.at[:, :, pages, offs].set(
+                jnp.swapaxes(k, 1, 2).astype(self.dtype))
+            self.v_pages = self.v_pages.at[:, :, pages, offs].set(
+                jnp.swapaxes(v, 1, 2).astype(self.dtype))
 
     def write_decode_token(self, seq_id: int, k: jnp.ndarray, v: jnp.ndarray):
         """Append one decode token's KV across every layer in one fused
@@ -708,14 +727,14 @@ class PagedCache:
         if self.quantized:
             kq, ks = KQ.quantize(k, scale_dtype=self.k_scales.dtype)
             vq, vs = KQ.quantize(v, scale_dtype=self.v_scales.dtype)
-            self.k_pages = self.k_pages.at[:, page, off].set(kq)
-            self.v_pages = self.v_pages.at[:, page, off].set(vq)
-            self.k_scales = self.k_scales.at[:, page, off].set(ks)
-            self.v_scales = self.v_scales.at[:, page, off].set(vs)
+            self.k_pages = self.k_pages.at[:, :, page, off].set(kq)
+            self.v_pages = self.v_pages.at[:, :, page, off].set(vq)
+            self.k_scales = self.k_scales.at[:, :, page, off].set(ks)
+            self.v_scales = self.v_scales.at[:, :, page, off].set(vs)
         else:
-            self.k_pages = self.k_pages.at[:, page, off].set(
+            self.k_pages = self.k_pages.at[:, :, page, off].set(
                 k.astype(self.dtype))
-            self.v_pages = self.v_pages.at[:, page, off].set(
+            self.v_pages = self.v_pages.at[:, :, page, off].set(
                 v.astype(self.dtype))
 
     def gather_kv(self, seq_id: int, layer: int):
@@ -724,15 +743,15 @@ class PagedCache:
         self._require_pools()
         table = jnp.asarray(self.tables[seq_id], jnp.int32)
         length = self.lengths[seq_id]
-        k = self.k_pages[layer, table]
-        v = self.v_pages[layer, table]
+        k = self.k_pages[layer][:, table]                 # (Hkv, n, ps, D)
+        v = self.v_pages[layer][:, table]
         if self.quantized:
-            k = KQ.dequantize(k, self.k_scales[layer, table],
+            k = KQ.dequantize(k, self.k_scales[layer][:, table],
                               dtype=self.compute_dtype)
-            v = KQ.dequantize(v, self.v_scales[layer, table],
+            v = KQ.dequantize(v, self.v_scales[layer][:, table],
                               dtype=self.compute_dtype)
-        k = k.reshape(-1, self.kv_heads, self.head_dim)
-        v = v.reshape(-1, self.kv_heads, self.head_dim)
+        k = k.transpose(1, 2, 0, 3).reshape(-1, self.kv_heads, self.head_dim)
+        v = v.transpose(1, 2, 0, 3).reshape(-1, self.kv_heads, self.head_dim)
         return k[:length], v[:length]
 
     # -------------------------------------------- speculative write rollback

@@ -9,7 +9,7 @@ cost.  This module is the single source of that machinery:
 * ``KVQuantConfig`` — what the cache stores: ``fp32``/``bf16`` passthrough
   or ``int8`` payloads with symmetric scales at ``token`` (one scale per
   written token per kv head) or ``page`` (one scale per physical page per
-  kv head — the ``(P, Hkv)`` pool) granularity.
+  kv head — the ``(Hkv, P)`` pool) granularity.
 * ``quantize`` / ``dequantize`` — the symmetric round-to-nearest transform
   shared by every write/read fusion point (model cache tree, ``PagedCache``
   data path, kernel oracles).
@@ -19,11 +19,11 @@ cost.  This module is the single source of that machinery:
   pool — which the paged engine converts directly into deeper continuous
   batching.
 
-Scale-pool layouts (parallel to the ``k_pages``/``v_pages`` payload pools,
-one pool per K and V):
+Scale-pool layouts (parallel to the head-major ``(..., Hkv, P + 1,
+page_size, D)`` ``k_pages``/``v_pages`` payload pools, one pool per K and V):
 
-  token granularity : ``(..., P + 1, page_size, Hkv)``  — exact per write
-  page granularity  : ``(..., P + 1, Hkv)``             — cheapest storage;
+  token granularity : ``(..., Hkv, P + 1, page_size)``  — exact per write
+  page granularity  : ``(..., Hkv, P + 1)``             — cheapest storage;
                       appends requantize the touched page (PagedCache data
                       path only — the engine's fused path is per-token)
 
@@ -115,15 +115,15 @@ def quantize(x: jnp.ndarray, *, axes=(-1,), scale_dtype=jnp.float32):
 def dequantize(q: jnp.ndarray, scales: jnp.ndarray, *, dtype=jnp.float32):
     """Inverse of ``quantize``; granularity is inferred from the rank gap.
 
-    ``q.ndim - scales.ndim == 1`` — per-token ``(..., Hkv)`` scales over
-    ``(..., Hkv, D)`` payloads; ``== 2`` — per-page ``(..., Hkv)`` scales
-    over ``(..., page_size, Hkv, D)`` payloads.
+    ``q.ndim - scales.ndim == 1`` — per-token scales over payloads with one
+    more (D) axis; ``== 2`` — per-page ``(..., Hkv, P)`` scales over
+    head-major ``(..., Hkv, P, page_size, D)`` payloads.
     """
     gap = q.ndim - scales.ndim
     if gap == 1:                       # token: broadcast over D
         s = scales[..., None]
     elif gap == 2:                     # page: broadcast over (position, D)
-        s = scales[..., None, :, None]
+        s = scales[..., None, None]
     else:
         raise ValueError(
             f"scale rank {scales.ndim} does not match payload rank {q.ndim} "
@@ -134,12 +134,12 @@ def dequantize(q: jnp.ndarray, scales: jnp.ndarray, *, dtype=jnp.float32):
 # --------------------------------------------------------------- scale shapes
 def paged_scale_shape(num_pages: int, page_size: int, kv_heads: int,
                       granularity: str) -> tuple[int, ...]:
-    """Per-layer scale-pool shape parallel to a ``(num_pages + 1, page_size,
-    Hkv, D)`` payload pool (null page included)."""
+    """Per-layer scale-pool shape parallel to a head-major ``(Hkv,
+    num_pages + 1, page_size, D)`` payload pool (null page included)."""
     if granularity == "token":
-        return (num_pages + 1, page_size, kv_heads)
+        return (kv_heads, num_pages + 1, page_size)
     if granularity == "page":
-        return (num_pages + 1, kv_heads)
+        return (kv_heads, num_pages + 1)
     raise ValueError(f"unknown granularity {granularity!r}")
 
 

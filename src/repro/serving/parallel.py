@@ -139,25 +139,23 @@ def param_specs(params, axis: str, tp: int):
 
 def cache_specs(cache, axis: str, tp: int = 1):
     """PartitionSpec tree for a paged cache tree: the ``num_kv_heads`` axis
-    of the page pools (``k_pages``/``v_pages``: ``(..., pages, page_size,
-    Hkv, D)``) and scale pools (``k_scales``/``v_scales``: ``(..., pages,
-    page_size, Hkv)``) is sharded; page ids stay global."""
+    of the head-major page pools (``k_pages``/``v_pages``: ``(layers, Hkv,
+    pages, page_size, D)``) and scale pools (``k_scales``/``v_scales``:
+    ``(layers, Hkv, pages, page_size)``) is sharded — axis 1 of every leaf,
+    so each device's pool is a whole head-slice of every page; page ids
+    stay global."""
 
     def spec(path, leaf):
         name = _entry_name(path[-1]) if path else ""
-        if name.endswith("_pages"):
-            shard_axis = -2
-        elif name.endswith("_scales"):
-            shard_axis = -1
-        else:
+        if not name.endswith(("_pages", "_scales")):
             raise ValueError(
                 f"unrecognized paged-cache leaf {name!r} — tensor-parallel "
                 f"serving knows k/v_pages and k/v_scales pools only")
-        if leaf.shape[shard_axis] % tp:
+        if leaf.shape[1] % tp:
             raise ValueError(
-                f"{name}: num_kv_heads={leaf.shape[shard_axis]} does not "
+                f"{name}: num_kv_heads={leaf.shape[1]} does not "
                 f"divide tp={tp}")
-        return _matrix_spec(leaf.ndim, shard_axis, axis)
+        return _matrix_spec(leaf.ndim, 1 - leaf.ndim, axis)
 
     return jax.tree_util.tree_map_with_path(spec, cache)
 
@@ -208,11 +206,13 @@ def shard_params(ctx: TPContext, params):
     return _device_put_tree(ctx.mesh, params, ctx.param_specs)
 
 
-def shard_cache(ctx: TPContext, cache):
-    """Commit a freshly initialized paged cache tree to its head-sharded
-    layout."""
-    return _device_put_tree(ctx.mesh, cache,
-                            cache_specs(cache, ctx.axis, ctx.tp))
+def init_cache(ctx: TPContext, init):
+    """Build the paged cache tree ``init()`` returns straight into its
+    head-sharded layout: each device allocates only its own head-slice (the
+    global pool is ``tp`` times a device's share and need not fit on one)."""
+    specs = cache_specs(jax.eval_shape(init), ctx.axis, ctx.tp)
+    return jax.jit(init, out_shardings=jax.tree_util.tree_map(
+        lambda s: NamedSharding(ctx.mesh, s), specs))()
 
 
 def localize_quantized(params):
@@ -270,6 +270,35 @@ def tp_wrap_fused(ctx: TPContext, kernels, impl):
         return fn(params, tokens, chunk_lens, drafts, draft_lens, emit,
                   cache, seq_lens, block_tables, live, greedy, temps,
                   top_ks, top_ps, keys, draft_probs)
+
+    return wrapped
+
+
+def tp_wrap_forward(ctx: TPContext, kernels):
+    """shard_map wrapper for ``model.forward_chunks`` with ``logit_idx`` —
+    the probe ``Engine.forward_probe`` checks a sharded engine's logits
+    with.  Same placement as ``tp_wrap_fused``: params and cache sharded,
+    everything else and the logits replicated."""
+    rep = P()
+
+    def wrapped(params, tokens, chunk_lens, cache, seq_lens, block_tables,
+                logit_idx):
+        def body(params, tokens, chunk_lens, cache, seq_lens, block_tables,
+                 logit_idx):
+            params = localize_quantized(params)
+            with L.tp_epilogue(ctx.axis):
+                return ctx.local_model.forward_chunks(
+                    params, tokens, chunk_lens, cache, seq_lens,
+                    kernels=kernels, block_tables=block_tables,
+                    logit_idx=logit_idx)
+
+        cspecs = cache_specs(cache, ctx.axis, ctx.tp)
+        fn = _shard_map(body, ctx.mesh,
+                        in_specs=(ctx.param_specs, rep, rep, cspecs, rep,
+                                  rep, rep),
+                        out_specs=(rep, cspecs))
+        return fn(params, tokens, chunk_lens, cache, seq_lens, block_tables,
+                  logit_idx)
 
     return wrapped
 

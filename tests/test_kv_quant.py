@@ -106,8 +106,9 @@ def test_dequantize_rejects_rank_mismatch():
 # ------------------------------------------------------------------- kernels
 def _random_paged(rng, b, h, hkv, d, pages, ps, maxp, lens):
     q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(pages, ps, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(pages, ps, hkv, d)), jnp.float32)
+    # head-major pools (Hkv, P, page_size, D)
+    kp = jnp.asarray(rng.normal(size=(hkv, pages, ps, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(hkv, pages, ps, d)), jnp.float32)
     bt = jnp.asarray(rng.permutation(pages)[:b * maxp].reshape(b, maxp),
                      jnp.int32)
     return q, kp, vp, bt, jnp.asarray(lens, jnp.int32)
@@ -122,7 +123,7 @@ def test_paged_attention_int8_matches_ref(granularity, h, hkv):
     b, d, pages, ps, maxp = 3, 64, 17, 8, 5
     q, kp, vp, bt, lens = _random_paged(rng, b, h, hkv, d, pages, ps, maxp,
                                         [1, 11, maxp * ps])
-    axes = (-1,) if granularity == "token" else (1, 3)
+    axes = (-1,) if granularity == "token" else (2, 3)
     kq, ks = KQ.quantize(kp, axes=axes)
     vq, vs = KQ.quantize(vp, axes=axes)
     out = paged_attention(q, kq, vq, bt, lens, k_scales=ks, v_scales=vs)

@@ -26,8 +26,9 @@ def small_lm():
 # ------------------------------------------------------------------- kernel
 def _random_paged(rng, b, h, hkv, d, pages, ps, maxp, lens):
     q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(pages, ps, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(pages, ps, hkv, d)), jnp.float32)
+    # head-major pools (Hkv, P, page_size, D)
+    kp = jnp.asarray(rng.normal(size=(hkv, pages, ps, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(hkv, pages, ps, d)), jnp.float32)
     bt = jnp.asarray(rng.permutation(pages)[:b * maxp].reshape(b, maxp),
                      jnp.int32)
     return q, kp, vp, bt, jnp.asarray(lens, jnp.int32)
@@ -55,8 +56,8 @@ def test_paged_attention_matches_contiguous_flash_ref():
     out = paged_attention(q, kp, vp, bt, lens)
     for i in range(b):
         L = int(lens[i])
-        kc = kp[bt[i]].reshape(-1, hkv, d)[:L][None]
-        vc = vp[bt[i]].reshape(-1, hkv, d)[:L][None]
+        kc = kp[:, bt[i]].transpose(1, 2, 0, 3).reshape(-1, hkv, d)[:L][None]
+        vc = vp[:, bt[i]].transpose(1, 2, 0, 3).reshape(-1, hkv, d)[:L][None]
         ref = flash_attention_ref(q[i][None, None], kc, vc, causal=True)
         np.testing.assert_allclose(np.asarray(out[i]), np.asarray(ref[0, 0]),
                                    rtol=2e-5, atol=2e-5)
@@ -74,8 +75,8 @@ def test_paged_attention_ignores_pages_past_length():
     used = {int(bt[i, j]) for i in range(b)
             for j in range(-(-int(lens[i]) // ps))}
     clobber = [p for p in range(pages) if p not in used]
-    kp2 = kp.at[jnp.asarray(clobber)].set(1e9)
-    vp2 = vp.at[jnp.asarray(clobber)].set(-1e9)
+    kp2 = kp.at[:, jnp.asarray(clobber)].set(1e9)
+    vp2 = vp.at[:, jnp.asarray(clobber)].set(-1e9)
     out2 = paged_attention(q, kp2, vp2, bt, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2))
 
@@ -445,14 +446,15 @@ def test_engine_paged_mixed_sampling(small_lm):
 # ------------------------------------------------------------------ dtypes
 def test_cache_dtype_single_source_and_respected(small_lm):
     cfg, model, params = small_lm
-    # default flows from DEFAULT_CACHE_DTYPE in both layouts
+    # the engine's default is the model's dtype in both layouts; a
+    # standalone cache manager defaults to DEFAULT_CACHE_DTYPE
     eng = Engine(model, params, batch_slots=1, max_len=16, eos_id=-1)
     leaf = jax.tree_util.tree_leaves(eng.slots.cache)[0]
-    assert leaf.dtype == DEFAULT_CACHE_DTYPE
+    assert leaf.dtype == jnp.dtype(cfg.dtype)
     engp = Engine(model, params, batch_slots=1, max_len=16, eos_id=-1,
                   cache="paged", page_size=4)
     leafp = jax.tree_util.tree_leaves(engp.cache)[0]
-    assert leafp.dtype == DEFAULT_CACHE_DTYPE
+    assert leafp.dtype == jnp.dtype(cfg.dtype)
     assert PagedCache(num_pages=2, page_size=2, n_layers=1, kv_heads=1,
                       head_dim=2).k_pages.dtype == DEFAULT_CACHE_DTYPE
     # and an explicit override is respected in both layouts

@@ -36,8 +36,9 @@ def small_lm():
 # -------------------------------------------------------------------- kernel
 def _random_prefill(rng, b, s, h, hkv, d, pages, ps, maxp, starts, wlens):
     q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(pages, ps, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(pages, ps, hkv, d)), jnp.float32)
+    # head-major pools (Hkv, P, page_size, D)
+    kp = jnp.asarray(rng.normal(size=(hkv, pages, ps, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(hkv, pages, ps, d)), jnp.float32)
     bt = jnp.asarray((rng.permutation(pages - 1) + 1)[:b * maxp]
                      .reshape(b, maxp), jnp.int32)
     st = jnp.asarray(starts, jnp.int32)
@@ -58,7 +59,7 @@ def test_paged_prefill_matches_ref(granularity, ragged, h, hkv):
         rng, b, s, h, hkv, d, pages, ps, maxp, [0, 4, 12], wlens)
     ks = vs = None
     if granularity is not None:
-        axes = (-1,) if granularity == "token" else (1, 3)
+        axes = (-1,) if granularity == "token" else (2, 3)
         kp, ks = quantize(kp, axes=axes)
         vp, vs = quantize(vp, axes=axes)
     out = paged_prefill(q, kp, vp, bt, st, lens, k_scales=ks, v_scales=vs,
@@ -90,12 +91,13 @@ def test_paged_prefill_matches_contiguous_flash_ref():
     vc = jnp.asarray(rng.normal(size=(b, s, hkv, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
-    kp = jnp.zeros((5, ps, hkv, d), jnp.float32)
-    vp = jnp.zeros((5, ps, hkv, d), jnp.float32)
+    kp = jnp.zeros((hkv, 5, ps, d), jnp.float32)
+    vp = jnp.zeros((hkv, 5, ps, d), jnp.float32)
     for i in range(b):
         for lp in range(maxp):
-            kp = kp.at[bt[i, lp]].set(kc[i, lp * ps:(lp + 1) * ps])
-            vp = vp.at[bt[i, lp]].set(vc[i, lp * ps:(lp + 1) * ps])
+            span = slice(lp * ps, (lp + 1) * ps)
+            kp = kp.at[:, bt[i, lp]].set(kc[i, span].transpose(1, 0, 2))
+            vp = vp.at[:, bt[i, lp]].set(vc[i, span].transpose(1, 0, 2))
     st = jnp.zeros((b,), jnp.int32)
     lens = jnp.full((b,), s, jnp.int32)
     out = paged_prefill(q, kp, vp, bt, st, lens, q_chunk=4)
@@ -122,7 +124,7 @@ def test_overrun_write_routes_to_null_page(small_lm):
     p = A.gqa_init(jax.random.key(1), cfg)
     ps, maxp, pages = 4, 2, 5
     rng = np.random.default_rng(4)
-    shape = (pages + 1, ps, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_kv_heads, pages + 1, ps, cfg.head_dim)
     cache = {"k_pages": jnp.asarray(rng.normal(size=shape), jnp.float32),
              "v_pages": jnp.asarray(rng.normal(size=shape), jnp.float32)}
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
@@ -133,14 +135,15 @@ def test_overrun_write_routes_to_null_page(small_lm):
                         block_tables=bt)
     for page in (1, 2, 4, 5):      # row 0's own pages + unowned neighbors
         np.testing.assert_array_equal(
-            np.asarray(nc["k_pages"][page]), np.asarray(cache["k_pages"][page]),
+            np.asarray(nc["k_pages"][:, page]),
+            np.asarray(cache["k_pages"][:, page]),
             err_msg=f"page {page} corrupted by overrun write")
     # the overrun write went somewhere: the null page absorbed it
-    assert not np.array_equal(np.asarray(nc["k_pages"][0]),
-                              np.asarray(cache["k_pages"][0]))
+    assert not np.array_equal(np.asarray(nc["k_pages"][:, 0]),
+                              np.asarray(cache["k_pages"][:, 0]))
     # row 1 (in range) still wrote normally: page 3, offset 1
-    assert not np.array_equal(np.asarray(nc["k_pages"][3]),
-                              np.asarray(cache["k_pages"][3]))
+    assert not np.array_equal(np.asarray(nc["k_pages"][:, 3]),
+                              np.asarray(cache["k_pages"][:, 3]))
 
 
 def test_slot_bucket_padding_never_writes_past_capacity(small_lm):

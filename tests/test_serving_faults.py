@@ -71,9 +71,10 @@ def _stamped_cache(n_layers=1, kv_heads=1, head_dim=2, page_size=4,
                    num_pages=16):
     pc = PagedCache(num_pages=num_pages, page_size=page_size,
                     n_layers=n_layers, kv_heads=kv_heads, head_dim=head_dim)
-    # make every physical page's payload identifiable
-    n = pc.k_pages.shape[1]
-    pc.k_pages = jnp.arange(n, dtype=pc.dtype).reshape(1, n, 1, 1, 1) * (
+    # make every physical page's payload identifiable (pools are head-major:
+    # (layers, Hkv, pages, page_size, D))
+    n = pc.k_pages.shape[2]
+    pc.k_pages = jnp.arange(n, dtype=pc.dtype).reshape(1, 1, n, 1, 1) * (
         jnp.ones_like(pc.k_pages))
     pc.v_pages = pc.k_pages * 2 + 1
     return pc
@@ -84,8 +85,8 @@ def test_offload_restore_round_trip_bit_identical():
     toks = list(range(10))
     assert pc.alloc_seq(0, 10, tokens=toks, reserve=2)
     tab = list(pc.tables[0])
-    want_k = np.asarray(pc.k_pages)[:, tab[:3]]
-    want_v = np.asarray(pc.v_pages)[:, tab[:3]]
+    want_k = np.asarray(pc.k_pages)[:, :, tab[:3]]
+    want_v = np.asarray(pc.v_pages)[:, :, tab[:3]]
     free_before = len(pc.free_list)
 
     rec = pc.offload(0)
@@ -108,8 +109,10 @@ def test_offload_restore_round_trip_bit_identical():
     assert r.hit_pages == 0 and r.snap_start_page == 0
     tab2 = pc.tables[0]
     assert pc.lengths[0] == 10
-    np.testing.assert_array_equal(np.asarray(pc.k_pages)[:, tab2[:3]], want_k)
-    np.testing.assert_array_equal(np.asarray(pc.v_pages)[:, tab2[:3]], want_v)
+    np.testing.assert_array_equal(np.asarray(pc.k_pages)[:, :, tab2[:3]],
+                                  want_k)
+    np.testing.assert_array_equal(np.asarray(pc.v_pages)[:, :, tab2[:3]],
+                                  want_v)
     assert not pc.offloaded and pc.offloaded_bytes == 0
 
 

@@ -171,11 +171,12 @@ def test_cache_specs_rejects_unknown_leaf():
     with pytest.raises(ValueError, match="unrecognized"):
         PL.cache_specs({"attn": {"weird": np.zeros((2, 2))}}, "model", 1)
     from jax.sharding import PartitionSpec as P
+    # head-major pools (layers, Hkv, pages, page_size, D): heads on axis 1
     specs = PL.cache_specs(
-        {"attn": {"k_pages": np.zeros((4, 5, 16, 4, 8)),
-                  "k_scales": np.zeros((4, 5, 16, 4))}}, "model", 2)
-    assert specs["attn"]["k_pages"] == P(None, None, None, "model", None)
-    assert specs["attn"]["k_scales"] == P(None, None, None, "model")
+        {"attn": {"k_pages": np.zeros((4, 4, 5, 16, 8)),
+                  "k_scales": np.zeros((4, 4, 5, 16))}}, "model", 2)
+    assert specs["attn"]["k_pages"] == P(None, "model", None, None, None)
+    assert specs["attn"]["k_scales"] == P(None, "model", None, None)
 
 
 # ------------------------------------------------------ device-labeled gauges

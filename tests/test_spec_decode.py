@@ -401,10 +401,11 @@ def _seq_bytes(pc, seq_id):
     """Raw payload (+scale) bytes of a sequence's pages, valid extent only
     implicitly included — page granularity stores whole-page state."""
     idx = np.asarray(pc.tables[seq_id], np.int32)
-    out = [np.asarray(pc.k_pages[:, idx]), np.asarray(pc.v_pages[:, idx])]
+    out = [np.asarray(pc.k_pages[:, :, idx]),
+           np.asarray(pc.v_pages[:, :, idx])]
     if pc.k_scales is not None:
-        out += [np.asarray(pc.k_scales[:, idx]),
-                np.asarray(pc.v_scales[:, idx])]
+        out += [np.asarray(pc.k_scales[:, :, idx]),
+                np.asarray(pc.v_scales[:, :, idx])]
     return out
 
 
