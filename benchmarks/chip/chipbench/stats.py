@@ -1,0 +1,14 @@
+"""Order statistics of the end-to-end metrics."""
+from __future__ import annotations
+
+import math
+
+
+def nearest_rank(values, q: float) -> float | None:
+    """The ``q`` quantile (0 < q <= 1) by nearest rank: the smallest value
+    with at least a share ``q`` of the values at or below it.  None for no
+    values."""
+    vals = sorted(values)
+    if not vals:
+        return None
+    return vals[max(0, math.ceil(q * len(vals)) - 1)]
