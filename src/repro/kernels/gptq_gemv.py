@@ -103,7 +103,8 @@ def gptq_gemv(x: jnp.ndarray, qweight: jnp.ndarray, scales: jnp.ndarray,
     if full_k_strategy(strategy):
         return fused_full_k(x, qweight, scales, qzeros, bias,
                             group_size=group_size, bm=8, bn=bn, bk=bk,
-                            out_dtype=out_dtype, interpret=interpret)
+                            out_dtype=out_dtype, interpret=interpret,
+                            name="gptq_gemv")
     if not (strategy.fused and strategy.accum_vmem):
         y = _gm.gptq_matmul(x, qweight, scales, qzeros,
                             group_size=group_size, strategy=strategy,
@@ -148,5 +149,6 @@ def gptq_gemv(x: jnp.ndarray, qweight: jnp.ndarray, scales: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((bm, n_pad), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="gptq_gemv",
     )(x, qweight, scales, qzeros, b)
     return y[:m, :n]

@@ -238,9 +238,11 @@ def _k_chunks(kw: int, rows_per_group: int, bk: int) -> tuple:
 def fused_full_k(x: jnp.ndarray, qweight: jnp.ndarray, scales: jnp.ndarray,
                  qzeros: jnp.ndarray, bias: jnp.ndarray | None, *,
                  group_size: int, bm: int, bn: int, bk: int, out_dtype,
-                 interpret: bool) -> jnp.ndarray:
+                 interpret: bool, name: str) -> jnp.ndarray:
     """The served W4A16 kernel (decode GEMV and prefill matmul alike).
-    x: (M, K); qweight: (K//8, N) int32; returns (M, N) + bias."""
+    x: (M, K); qweight: (K//8, N) int32; returns (M, N) + bias.  ``name``
+    is the device op's name in a profile (``gptq_gemv`` / ``gptq_matmul``),
+    kept stable whatever function encloses the call."""
     m, k = x.shape
     n = scales.shape[1]
     if k % NIB:
@@ -282,6 +284,7 @@ def fused_full_k(x: jnp.ndarray, qweight: jnp.ndarray, scales: jnp.ndarray,
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name=name,
     )(xd, qweight, scales, z, b)
     return y[:m, :n]
 
@@ -360,7 +363,7 @@ def gptq_matmul(x: jnp.ndarray, qweight: jnp.ndarray, scales: jnp.ndarray,
         return fused_full_k(x, qweight, scales, qzeros, None,
                             group_size=group_size, bm=bm, bn=bn, bk=bk,
                             out_dtype=out_dtype or x.dtype,
-                            interpret=interpret)
+                            interpret=interpret, name="gptq_matmul")
     g = group_size if group_size > 0 else k
     bm, bn, bk = resolve_block_sizes(m, k, n, group_size, bm, bn, bk)
     qweight, scales, qzeros, n_pad = pad_cols(qweight, scales, qzeros, n, bn)

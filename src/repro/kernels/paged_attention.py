@@ -223,6 +223,7 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *inputs)
     return out.reshape(b, h, d)
 
@@ -370,6 +371,7 @@ def paged_prefill(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, nq, chunk * rep, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill",
     )(block_tables.astype(jnp.int32), seq_start.astype(jnp.int32),
       lengths.astype(jnp.int32), *inputs)
     out = out.reshape(b, hkv, nq, chunk, rep, d).transpose(0, 2, 3, 1, 4, 5)

@@ -17,11 +17,18 @@ interpreter on the CPU (``kernels/lane.py``).
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3_4b --smoke \
       --serve --port 8000
 
-Observability (DESIGN.md §15): ``--trace-out trace.json`` attaches a
-step-span ``Tracer`` and writes a Chrome/Perfetto ``trace_event`` file on
-exit; ``--log-json`` switches the driver's own progress lines to one JSON
-object per line (machine-parseable event log); ``--no-metrics`` swaps the
-engine's registry for the zero-cost null one.
+Observability (DESIGN.md §15): every phase of a step (``engine.admit``,
+``engine.plan``, ``engine.stage``, ``engine.dispatch``, ``engine.wait``,
+``engine.emit``, ``engine.end_step`` inside ``engine.step``) and of the
+HTTP worker (``worker.inbox``, ``worker.fan_out``) is one span with two
+sinks: a ``jax.profiler.TraceAnnotation`` on the device trace's clock,
+seen by any profiler session, and -- with ``--trace-out trace.json``,
+which attaches a ``Tracer`` -- a slice of the same name in the
+Chrome/Perfetto ``trace_event`` file written on exit, beside the
+per-request lifecycle tracks.  ``--log-json`` switches this script's
+own progress lines to one JSON object per line (machine-parseable event
+log); ``--no-metrics`` swaps the engine's registry for the zero-cost null
+one.
 """
 import argparse
 import json

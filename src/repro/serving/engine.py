@@ -89,6 +89,7 @@ from repro.serving import kv_cache as KV
 from repro.serving import kv_quant as KQ
 from repro.serving import parallel as PL
 from repro.serving import spec_decode as SD
+from repro.serving import tracing as TR
 from repro.serving.api import (EngineConfig, FinishReason, QueueFullError,
                                RequestOutput, RequestState, StreamEvent)
 from repro.serving.metrics import EngineMetrics, make_engine_metrics
@@ -176,10 +177,6 @@ class EngineStats:
     def shed_requests(self) -> int:
         return int(self._m.shed_requests.value)
 
-    @property
-    def decode_throughput(self) -> float:
-        return self.tokens_generated / self.wall_s if self.wall_s else 0.0
-
     # ---- speculative decoding (DESIGN.md §16) ----
     @property
     def spec_proposed(self) -> int:
@@ -261,6 +258,14 @@ class Engine:
             else CLK.SYSTEM_CLOCK
         self.faults = config.faults
         self.tracer = config.tracer
+        if self.tracer is not None:
+            # the tracer's spans read the engine's clock (one tracer serves
+            # one engine)
+            self.tracer.clock = self.clock
+        TR.install_process_hooks()
+        # what the last step ran (``_step_record``); None before the first
+        # step and after a step with no live row
+        self.last_step: Optional[dict] = None
         self._step_no = 0
         self._next_rid = 0
         self._requests: dict[int, Request] = {}
@@ -1131,6 +1136,14 @@ class Engine:
         the HTTP worker thread, or a bare ``while: eng.step()`` loop —
         accounts identically into ``engine_wall_seconds_total``.
 
+        Each phase is a span (``serving/tracing.py``): ``engine.step``
+        around the whole and, in order inside it, ``engine.admit``,
+        ``engine.plan``, ``engine.stage`` (holding ``engine.propose`` under
+        speculation), ``engine.dispatch``, ``engine.wait`` (the blocking
+        transfer, also counted into ``engine_device_wait_seconds_total``),
+        ``engine.emit`` and ``engine.end_step``.  ``last_step`` records
+        what the step ran.
+
         Rollback is implicit under speculation: ``seq_lens`` (and the host
         page-length mirror) advance only to the accepted position;
         rejected positions' KV is dead weight that the next chunk
@@ -1139,7 +1152,17 @@ class Engine:
         the bonus token lands exactly on the reserved page/slot footprint,
         never past it.
         """
+        with TR.span("engine.step", self.tracer) as step_span:
+            # jit cache misses inside the step count against this engine
+            prev, TR.RUNNING.engine = TR.RUNNING.engine, self
+            try:
+                return self._step(step_span)
+            finally:
+                TR.RUNNING.engine = prev
+
+    def _step(self, step_span: TR.Span) -> list[RequestOutput]:
         t_step0 = self.clock.now()
+        tr = self.tracer
         if self.faults is not None:
             # deterministic fault injection (serving/faults.py): scheduled
             # page seizures, stalls and aborts fire before admissions
@@ -1147,10 +1170,13 @@ class Engine:
         if len(self._events) > self._MAX_PENDING_EVENTS:
             del self._events[:len(self._events) - self._MAX_PENDING_EVENTS]
         finished: list[RequestOutput] = []
-        self._admit(finished)
+        self.last_step = None
+        with TR.span("engine.admit", tr):
+            self._admit(finished)
         self.metrics.peak_active.set_max(len(self.sched.active))
         if not self.sched.active:
-            self._end_step(t_step0, finished, decoded=0)
+            with TR.span("engine.end_step", tr):
+                self._end_step(t_step0, finished, 0, step_span)
             return finished
         bs = self.batch_rows
         spec = self._spec
@@ -1159,148 +1185,172 @@ class Engine:
         # plain, k+1 under speculation), the remaining budget is dealt to
         # mid-prefill rows as prompt chunks; budget-starved prefill rows
         # sit this step out (live=False, writes masked, seq_lens kept)
-        plan = self.sched.plan_chunks(self.config.max_step_tokens,
-                                      reserve=k + 1)
-        decode_rows = {row: a for row, a in self.sched.active.items()
-                       if not a.pending_prefill}
-        # host-side staging: per-row sampling arrays + chunk spans (numpy,
-        # no device round-trips)
-        live = np.zeros((bs,), np.bool_)
-        emit = np.zeros((bs,), np.bool_)
-        chunk_lens = np.zeros((bs,), np.int32)
-        greedy = np.ones((bs,), np.bool_)
-        temps = np.ones((bs,), np.float32)
-        top_ks = np.zeros((bs,), np.int32)
-        top_ps = np.ones((bs,), np.float32)
-        for row, a in self.sched.active.items():
-            sp = a.req.sampling
-            greedy[row] = sp.greedy or sp.temperature == 0.0
-            temps[row] = sp.temperature if sp.temperature > 0.0 else 1.0
-            top_ks[row] = sp.top_k
-            top_ps[row] = sp.top_p
-        all_greedy = bool(greedy.all())
+        with TR.span("engine.plan", tr):
+            plan = self.sched.plan_chunks(self.config.max_step_tokens,
+                                          reserve=k + 1)
+        with TR.span("engine.stage", tr):
+            decode_rows = {row: a for row, a in self.sched.active.items()
+                           if not a.pending_prefill}
+            # host-side staging: per-row sampling arrays + chunk spans
+            # (numpy, no device round-trips)
+            live = np.zeros((bs,), np.bool_)
+            emit = np.zeros((bs,), np.bool_)
+            chunk_lens = np.zeros((bs,), np.int32)
+            greedy = np.ones((bs,), np.bool_)
+            temps = np.ones((bs,), np.float32)
+            top_ks = np.zeros((bs,), np.int32)
+            top_ps = np.ones((bs,), np.float32)
+            for row, a in self.sched.active.items():
+                sp = a.req.sampling
+                greedy[row] = sp.greedy or sp.temperature == 0.0
+                temps[row] = sp.temperature if sp.temperature > 0.0 else 1.0
+                top_ks[row] = sp.top_k
+                top_ps[row] = sp.top_p
+            all_greedy = bool(greedy.all())
 
-        # ---- speculative proposal (decode rows only) ----
-        lens = np.zeros((bs,), np.int32)
-        drafts_dev = jnp.zeros((bs, k), jnp.int32)
-        probs = None
-        proposed = 0
-        t_p1 = t_step0
-        if spec is not None and decode_rows:
-            rows: dict[int, tuple[int, list[int], int]] = {}
-            caps = np.zeros((bs,), np.int32)
-            for row, a in decode_rows.items():
-                cap = max(0, min(spec.k,
-                                 a.req.max_new_tokens - len(a.output) - 1))
-                rows[row] = (a.req.rid, a.req.tokens + a.output, cap)
-                caps[row] = cap
-            t_p0 = self.clock.now()
-            samp_host = None if all_greedy \
-                else (greedy, temps, top_ks, top_ps)
-            prop = spec.propose(rows, all_greedy=all_greedy, samp=samp_host)
-            lens = np.minimum(np.asarray(prop.draft_lens, np.int32), caps)
-            drafts_dev = prop.drafts \
-                if not isinstance(prop.drafts, np.ndarray) \
-                else jnp.asarray(prop.drafts)
-            probs = prop.probs
-            proposed = int(lens.sum())
-            t_p1 = self.clock.now()
-            self.metrics.spec_proposed.inc(proposed)
-            for row, a in decode_rows.items():
-                a.req.spec_proposed += int(lens[row])
-            if self.tracer is not None:
-                self.tracer.propose_span(t_p0, t_p1, step=self._step_no,
-                                         proposed=proposed,
-                                         batch=len(decode_rows))
+            # ---- speculative proposal (decode rows only) ----
+            lens = np.zeros((bs,), np.int32)
+            drafts_dev = jnp.zeros((bs, k), jnp.int32)
+            probs = None
+            if spec is not None and decode_rows:
+                with TR.span("engine.propose", tr, step=self._step_no,
+                             batch=len(decode_rows)) as propose_span:
+                    rows: dict[int, tuple[int, list[int], int]] = {}
+                    caps = np.zeros((bs,), np.int32)
+                    for row, a in decode_rows.items():
+                        cap = max(0, min(spec.k, a.req.max_new_tokens
+                                         - len(a.output) - 1))
+                        rows[row] = (a.req.rid, a.req.tokens + a.output, cap)
+                        caps[row] = cap
+                    samp_host = None if all_greedy \
+                        else (greedy, temps, top_ks, top_ps)
+                    prop = spec.propose(rows, all_greedy=all_greedy,
+                                        samp=samp_host)
+                    lens = np.minimum(np.asarray(prop.draft_lens, np.int32),
+                                      caps)
+                    drafts_dev = prop.drafts \
+                        if not isinstance(prop.drafts, np.ndarray) \
+                        else jnp.asarray(prop.drafts)
+                    probs = prop.probs
+                    proposed = int(lens.sum())
+                    self.metrics.spec_proposed.inc(proposed)
+                    for row, a in decode_rows.items():
+                        a.req.spec_proposed += int(lens[row])
+                    propose_span.set(proposed=proposed)
 
-        # ---- chunk staging: decode anchors (+drafts on device), prompt
-        # chunks from the plan ----
-        need = k + 1 if (spec is not None and decode_rows) else 1
-        if plan:
-            need = max(need, max(plan.values()))
-        width = self._step_width(need)
-        tokens = np.zeros((bs, width), np.int32)
-        for row, a in decode_rows.items():
-            live[row] = True
-            emit[row] = True
-            chunk_lens[row] = int(lens[row]) + 1
-            tokens[row, 0] = a.output[-1] if a.output else a.req.tokens[-1]
-        for row, c in plan.items():
-            a = self.sched.active[row]
-            live[row] = True
-            chunk_lens[row] = c
-            tokens[row, :c] = \
-                a.prefill_ctx[a.prefill_pos:a.prefill_pos + c]
-            # the chunk that completes the prompt emits the first token
-            # (restore-gap chunks never sample — the next token is already
-            # in the request's output)
-            emit[row] = (a.prefill_pos + c >= a.prefill_end
-                         and a.prefill_sample)
-        if all_greedy:
-            # argmax-only trace: no rng consumption, no sampling operands
-            samp = (None, None, None, None, None)
-        else:
-            self.rng, sub = jax.random.split(self.rng)
-            samp = (jnp.asarray(greedy), jnp.asarray(temps),
-                    jnp.asarray(top_ks), jnp.asarray(top_ps),
-                    jax.random.split(sub, bs))
-        head = (self.params, jnp.asarray(tokens), jnp.asarray(chunk_lens),
-                drafts_dev, jnp.asarray(lens), jnp.asarray(emit))
-        if self.layout == "paged":
-            pc = self.pc
-            packed_dev, self.cache, pc.seq_lens = self._fused(
-                *head, self.cache, pc.seq_lens, pc.block_tables,
-                jnp.asarray(live), *samp, probs, all_greedy=all_greedy)
-        else:
-            packed_dev, self.slots.cache, self.slots.seq_lens = self._fused(
-                *head, self.slots.cache, self.slots.seq_lens, None,
-                jnp.asarray(live), *samp, probs, all_greedy=all_greedy)
-        # the single device->host transfer of the step
-        packed = np.asarray(jax.device_get(packed_dev))
-        decoded = 0
-        accepted_total = 0
-        for row in sorted(self.sched.active):
-            a = self.sched.active[row]
-            rid = a.req.rid
-            if not live[row]:
-                continue
-            if row in plan:
-                self._advance_prefill(a, row, plan[row], packed, finished)
-                continue
-            n_emit = int(packed[row, 0])
-            n_acc = n_emit - 1
-            if self.layout == "paged":
-                self.pc.lengths[rid] += n_emit   # host seq_lens mirror
-            if spec is not None:
-                a.req.spec_accepted += n_acc
-                accepted_total += n_acc
-                self.metrics.spec_accepted.inc(n_acc)
-                self.metrics.spec_accept_len.observe(n_acc)
-            for tok in packed[row, 1:1 + n_emit].tolist():
-                decoded += 1
-                a.output.append(int(tok))
-                self._emit_token(a, row, int(tok), finished)
-                if row not in self.sched.active:
-                    # retired mid-span (stop token / length / abort): the
-                    # retirement already freed the row's device state, so
-                    # later emitted tokens are dropped with it
-                    break
+            # ---- chunk staging: decode anchors (+drafts on device), prompt
+            # chunks from the plan ----
+            need = k + 1 if (spec is not None and decode_rows) else 1
+            if plan:
+                need = max(need, max(plan.values()))
+            width = self._step_width(need)
+            tokens = np.zeros((bs, width), np.int32)
+            for row, a in decode_rows.items():
+                live[row] = True
+                emit[row] = True
+                chunk_lens[row] = int(lens[row]) + 1
+                tokens[row, 0] = a.output[-1] if a.output \
+                    else a.req.tokens[-1]
+            for row, c in plan.items():
+                a = self.sched.active[row]
+                live[row] = True
+                chunk_lens[row] = c
+                tokens[row, :c] = \
+                    a.prefill_ctx[a.prefill_pos:a.prefill_pos + c]
+                # the chunk that completes the prompt emits the first token
+                # (restore-gap chunks never sample — the next token is
+                # already in the request's output)
+                emit[row] = (a.prefill_pos + c >= a.prefill_end
+                             and a.prefill_sample)
+            if all_greedy:
+                # argmax-only trace: no rng consumption, no sampling operands
+                samp = (None, None, None, None, None)
             else:
+                self.rng, sub = jax.random.split(self.rng)
+                samp = (jnp.asarray(greedy), jnp.asarray(temps),
+                        jnp.asarray(top_ks), jnp.asarray(top_ps),
+                        jax.random.split(sub, bs))
+            head = (self.params, jnp.asarray(tokens), jnp.asarray(chunk_lens),
+                    drafts_dev, jnp.asarray(lens), jnp.asarray(emit))
+            live_dev = jnp.asarray(live)
+            self.last_step = self._step_record(plan, chunk_lens, emit, width)
+            step_span.set(rows=len(self.last_step["rows"]), width=width,
+                          tokens=int(chunk_lens.sum()),
+                          prefill_tokens=int(sum(plan.values())))
+        with TR.span("engine.dispatch", tr):
+            if self.layout == "paged":
+                pc = self.pc
+                packed_dev, self.cache, pc.seq_lens = self._fused(
+                    *head, self.cache, pc.seq_lens, pc.block_tables,
+                    live_dev, *samp, probs, all_greedy=all_greedy)
+            else:
+                packed_dev, self.slots.cache, self.slots.seq_lens = \
+                    self._fused(*head, self.slots.cache, self.slots.seq_lens,
+                                None, live_dev, *samp, probs,
+                                all_greedy=all_greedy)
+        with TR.span("engine.wait", tr):
+            t_wait = self.clock.now()
+            # the single device->host transfer of the step
+            packed = np.asarray(jax.device_get(packed_dev))
+            self.metrics.device_wait_seconds.inc(self.clock.now() - t_wait)
+        with TR.span("engine.emit", tr):
+            decoded = 0
+            accepted_total = 0
+            for row in sorted(self.sched.active):
+                a = self.sched.active[row]
+                rid = a.req.rid
+                if not live[row]:
+                    continue
+                if row in plan:
+                    self._advance_prefill(a, row, plan[row], packed,
+                                          finished)
+                    continue
+                n_emit = int(packed[row, 0])
+                n_acc = n_emit - 1
+                if self.layout == "paged":
+                    self.pc.lengths[rid] += n_emit   # host seq_lens mirror
                 if spec is not None:
-                    spec.observe(row, rid, n_acc)
-        m = self.metrics
-        m.tokens_generated.inc(decoded)
-        m.steps.inc()
-        if spec is not None and decode_rows:
-            m.spec_verify_steps.inc()
-            if self.tracer is not None:
-                self.tracer.verify_span(t_p1, self.clock.now(),
-                                        step=self._step_no,
-                                        proposed=proposed,
-                                        accepted=accepted_total,
-                                        decoded=decoded)
-        self._end_step(t_step0, finished, decoded=decoded)
+                    a.req.spec_accepted += n_acc
+                    accepted_total += n_acc
+                    self.metrics.spec_accepted.inc(n_acc)
+                    self.metrics.spec_accept_len.observe(n_acc)
+                for tok in packed[row, 1:1 + n_emit].tolist():
+                    decoded += 1
+                    a.output.append(int(tok))
+                    self._emit_token(a, row, int(tok), finished)
+                    if row not in self.sched.active:
+                        # retired mid-span (stop token / length / abort):
+                        # the retirement already freed the row's device
+                        # state, so later emitted tokens are dropped with it
+                        break
+                else:
+                    if spec is not None:
+                        spec.observe(row, rid, n_acc)
+            m = self.metrics
+            m.tokens_generated.inc(decoded)
+            m.steps.inc()
+            if spec is not None and decode_rows:
+                m.spec_verify_steps.inc()
+                step_span.set(accepted=accepted_total)
+        with TR.span("engine.end_step", tr):
+            self._end_step(t_step0, finished, decoded, step_span)
         return finished
+
+    def _step_record(self, plan: dict, chunk_lens, emit,
+                     width: int) -> dict:
+        """``last_step``: the step's batch rows and width, and for each
+        live row ``[start, chunk, emits]`` in admission order — a prefill
+        chunk starts at the row's prefill position, a decode row at its
+        context (the tokens already in its cache)."""
+        rows = []
+        for row, a in self.sched.active.items():
+            if row in plan:
+                rows.append([a.prefill_pos, plan[row], bool(emit[row])])
+            elif not a.pending_prefill:
+                ctx = (self.pc.lengths[a.req.rid] if self.layout == "paged"
+                       else len(a.req.tokens) + len(a.output) - 1)
+                rows.append([ctx, int(chunk_lens[row]), True])
+        return {"batch": self.batch_rows, "width": width, "rows": rows}
 
     def _advance_prefill(self, a: Active, row: int, c: int, packed,
                          finished: list[RequestOutput]) -> None:
@@ -1346,10 +1396,11 @@ class Engine:
         self._emit_token(a, row, tok, finished)
 
     def _end_step(self, t0: float, finished: list[RequestOutput],
-                  decoded: int) -> None:
+                  decoded: int, step_span: TR.Span) -> None:
         """Close out one ``step()``: wall/duration accounting, occupancy
-        gauges, and the step's trace span.  Host-side bookkeeping only —
-        nothing here touches a device value."""
+        gauges, and the step span's annotations for the Perfetto export.
+        Host-side bookkeeping only — nothing here touches a device
+        value."""
         t1 = self.clock.now()
         m = self.metrics
         m.wall_seconds.inc(t1 - t0)
@@ -1366,7 +1417,7 @@ class Engine:
                 occ = self.pc.occupancy()
                 args["free_pages"] = occ["free_pages"]
                 args["pool_utilization"] = round(occ["utilization"], 6)
-            self.tracer.step_span(t0, t1, **args)
+            step_span.set(**args)
         self._step_no += 1
 
     def drain_events(self) -> list[StreamEvent]:

@@ -38,6 +38,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from repro.runtime.fault_tolerance import Heartbeat
 from repro.serving.api import FinishReason, QueueFullError, StreamEvent
 from repro.serving.sampler import SamplingParams
+from repro.serving.tracing import span
 
 # how long a handler waits for the next token before giving up on the worker
 EVENT_TIMEOUT_S = 300.0
@@ -64,7 +65,10 @@ class EngineWorker(threading.Thread):
     Drains control ops (submit/abort) from ``inbox``, pumps
     ``Engine.step_events()`` while requests are in flight, and fans each
     event out to its request's subscriber queue.  Idle polling is a short
-    blocking ``inbox.get`` — no busy loop.
+    blocking ``inbox.get`` — no busy loop.  Its work outside
+    ``Engine.step`` (submits, aborts, fan-out) is spanned as
+    ``worker.inbox`` / ``worker.fan_out`` and counted into
+    ``engine_frontend_seconds_total``; the idle wait is ``worker.idle``.
 
     With ``stall_timeout_s`` set, the loop beats a ``Heartbeat`` (read
     through the engine's injectable clock) each iteration and a watchdog
@@ -169,6 +173,16 @@ class EngineWorker(threading.Thread):
                 self._fail_subs(FinishReason.STALL)
             self._halt.wait(poll_s)
 
+    def _front(self, name: str, fn, *args):
+        """Front-end work on the engine thread, outside ``Engine.step``: a
+        ``name`` span, its clock time counted into
+        ``engine_frontend_seconds_total``."""
+        eng = self.eng
+        with span(name, eng.tracer):
+            t0 = eng.clock.now()
+            fn(*args)
+            eng.metrics.frontend_seconds.inc(eng.clock.now() - t0)
+
     def run(self):
         while not self._halt.is_set():
             if self.heartbeat is not None:
@@ -178,18 +192,25 @@ class EngineWorker(threading.Thread):
                     op, payload = self.inbox.get_nowait()
                 except queue.Empty:
                     break
-                self._handle(op, payload)
+                self._front("worker.inbox", self._handle, op, payload)
             if self.eng.sched.idle:
                 # an abort that idled the engine leaves its terminal event
                 # pending — deliver it (and release the _subs entry) now
-                self._fan_out(self.eng.drain_events())
-                try:
-                    op, payload = self.inbox.get(timeout=self.idle_poll_s)
-                except queue.Empty:
-                    continue
-                self._handle(op, payload)
+                events = self.eng.drain_events()
+                if events:
+                    self._front("worker.fan_out", self._fan_out, events)
+                # waiting for a request: a profiler span only (an idle
+                # server would fill the Perfetto export with polls)
+                with span("worker.idle"):
+                    try:
+                        op, payload = self.inbox.get(
+                            timeout=self.idle_poll_s)
+                    except queue.Empty:
+                        continue
+                self._front("worker.inbox", self._handle, op, payload)
                 continue
-            self._fan_out(self.eng.step_events())
+            events = self.eng.step_events()
+            self._front("worker.fan_out", self._fan_out, events)
 
 
 # --------------------------------------------------------------- HTTP layer

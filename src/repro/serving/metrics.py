@@ -496,6 +496,20 @@ class EngineMetrics:
         self.wall_seconds = r.counter(
             "engine_wall_seconds_total",
             "Clock seconds spent inside Engine.step (injectable clock)")
+        # where a step's time goes (DESIGN.md §15): wall minus device wait
+        # is the host's share of the step; the front end's share lies
+        # outside Engine.step
+        self.device_wait_seconds = r.counter(
+            "engine_device_wait_seconds_total",
+            "Clock seconds Engine.step spends blocked in its device->host "
+            "transfer")
+        self.frontend_seconds = r.counter(
+            "engine_frontend_seconds_total",
+            "Clock seconds the HTTP engine worker spends on submits, aborts "
+            "and event fan-out, outside Engine.step and idle waits")
+        self.compiles = r.counter(
+            "engine_compiles_total",
+            "JAX traces (jit cache misses) while Engine.step runs")
         self.prefix_hit_pages = r.counter(
             "engine_prefix_hit_pages_total",
             "KV pages served from the hashed-prefix cache")

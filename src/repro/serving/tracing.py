@@ -1,19 +1,39 @@
-"""Per-request step-span tracing with Chrome/Perfetto export (DESIGN.md §15).
+"""Step phases as spans, with two sinks, and per-request tracing with
+Chrome/Perfetto export (DESIGN.md §15).
 
-A ``Tracer`` attached via ``EngineConfig.tracer`` records two kinds of
-timeline, timestamped exclusively through the engine's injectable clock
-(``serving/clock.py`` — under a ``ManualClock`` the exported trace is
-byte-deterministic across runs):
+**One span API.**  ``span(name, tracer)`` is the one way the serving stack
+names a phase of its work.  It always opens a
+``jax.profiler.TraceAnnotation`` of that name, which lands on the device
+trace's clock whenever a profiler session is running (and costs about a
+microsecond when none is).  When a ``Tracer`` is given, the span also
+records an ``X`` slice of the same name on the engine track of the
+Perfetto export, timed by the engine's injectable clock.  So a profiler
+trace and ``launch/serve.py --trace-out`` name the same phases:
+``engine.step`` around each ``Engine.step`` and, inside it,
+``engine.admit``, ``engine.plan``, ``engine.stage`` (with
+``engine.propose`` when a speculator runs), ``engine.dispatch``,
+``engine.wait``, ``engine.emit`` and ``engine.end_step``; on the HTTP
+engine worker ``worker.inbox``, ``worker.fan_out`` and ``worker.idle``
+(profiler only: an idle server polls); and ``engine.gc`` around each
+garbage-collector pause (profiler only, process-wide).
 
-* **request lifecycle spans** on one Perfetto track per request
-  (pid ``PID_REQUESTS``, tid = rid): QUEUED → PREFILL → RUNNING →
-  PREEMPTED → RESTORED-RUNNING → terminal instant (``finish`` with the
-  ``FinishReason``).  Offload/restore page movement and injected faults
-  (``serving/faults.py``) land as instant events on the same tracks.
-* **engine step spans** on the engine track (pid ``PID_ENGINE``): one
-  ``X`` slice per ``Engine.step`` carrying batch size, queue depth, and
-  page-pool occupancy annotations; prefill slices carry the bucketed
-  chunk length and page-reservation annotations.
+**Process hooks.**  ``install_process_hooks`` (called by every ``Engine``,
+effective once per process) adds the ``engine.gc`` hook and one JAX
+monitoring listener that counts jaxpr traces -- one per jit cache miss --
+into ``engine_compiles_total`` of the engine whose step runs on the
+calling thread (``RUNNING``).
+
+**Per-request tracing.**  A ``Tracer`` attached via ``EngineConfig.tracer``
+also records request lifecycle spans on one Perfetto track per request
+(pid ``PID_REQUESTS``, tid = rid): QUEUED -> PREFILL -> RUNNING ->
+PREEMPTED -> RESTORED-RUNNING -> terminal instant (``finish`` with the
+``FinishReason``).  Offload/restore page movement and injected faults
+(``serving/faults.py``) land as instant events; prefill work slices carry
+the bucketed chunk length and page-reservation annotations.  Every
+timestamp comes from the engine's injectable clock (``serving/clock.py``):
+the engine passes it in, or binds it as ``Tracer.clock`` for spans, so
+under a ``ManualClock`` the exported trace is byte-deterministic across
+runs.
 
 Export is the Chrome ``trace_event`` JSON-object format (the one
 ``about:tracing`` and https://ui.perfetto.dev load directly):
@@ -23,15 +43,18 @@ slices and scoped ``i`` instants.  ``validate_trace`` is the schema check
 the tests and the CI artifact gate run over exported files.
 
 Tracing is pure host-side bookkeeping: no device value is ever read for a
-span (the engine's one device->host transfer per decode step is unchanged,
-and greedy outputs are bit-identical with tracing on or off — both tested).
-``Tracer(enabled=False)`` (or simply no tracer) is the opt-out; every
-record call short-circuits on one attribute check.
+span (the engine's one device->host transfer per step is unchanged, and
+greedy outputs are bit-identical with tracing on or off -- both tested).
+``Tracer(enabled=False)`` (or simply no tracer) is the opt-out for the
+Perfetto sink; every record call short-circuits on one attribute check.
 """
 from __future__ import annotations
 
+import gc
 import json
-from typing import Optional
+import threading
+
+import jax
 
 PID_ENGINE = 1
 PID_REQUESTS = 2
@@ -44,6 +67,10 @@ CAT_FAULT = "fault"
 
 _ALLOWED_PH = {"M", "X", "i"}
 
+# the monitoring event JAX records each time it traces a function to a
+# jaxpr, which happens on every jit cache miss
+JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+
 
 def _us(t: float) -> float:
     """Seconds -> integer-friendly microseconds (rounded to 0.1us so float
@@ -51,16 +78,105 @@ def _us(t: float) -> float:
     return round(float(t) * 1e6, 1)
 
 
+class Span:
+    """One named phase: a profiler annotation and, with a ``Tracer``, an
+    ``X`` slice on the engine track.  Use it through ``span``."""
+
+    __slots__ = ("_ann", "_name", "_tracer", "_args", "_t0")
+
+    def __init__(self, name: str, tracer: "Tracer | None", args: dict):
+        self._name = name
+        self._tracer = tracer if tracer is not None and tracer.enabled \
+            else None
+        self._args = args
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._t0 = self._tracer.clock.now()
+        return self
+
+    def set(self, **args) -> None:
+        """Attach ``args`` to the span in both sinks (values known only
+        after the span opened, such as a step's width)."""
+        self._ann.set_metadata(**args)
+        if self._tracer is not None:
+            self._args.update(args)
+
+    def __exit__(self, *exc) -> None:
+        if self._tracer is not None:
+            self._tracer.complete(self._name, CAT_STEP, PID_ENGINE,
+                                  TID_STEPS, self._t0,
+                                  self._tracer.clock.now(), **self._args)
+        self._ann.__exit__(*exc)
+
+
+def span(name: str, tracer: "Tracer | None" = None, **args) -> Span:
+    """``with span("engine.wait", eng.tracer):`` -- the phase as a
+    profiler annotation and, when ``tracer`` is given, a Perfetto slice of
+    the same name carrying ``args``."""
+    return Span(name, tracer, args)
+
+
+class _Running(threading.local):
+    """The engine whose ``step`` runs on this thread, if any."""
+    engine = None
+
+
+RUNNING = _Running()
+_hooks_lock = threading.Lock()
+_hooks_installed = False
+_gc_span = None
+
+
+def _on_gc(phase: str, _info: dict) -> None:
+    # gc callbacks of one collection run on one thread under the
+    # interpreter lock, and collections never nest
+    global _gc_span
+    if phase == "start":
+        if not jax.profiler.TraceAnnotation.is_enabled():
+            return      # no profiler session: collections are frequent
+        _gc_span = jax.profiler.TraceAnnotation("engine.gc")
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        _gc_span.__exit__(None, None, None)
+        _gc_span = None
+
+
+def _on_event_duration(event: str, _secs: float, **_kwargs) -> None:
+    if event == JAXPR_TRACE_EVENT:
+        eng = RUNNING.engine
+        if eng is not None:
+            eng.metrics.compiles.inc()
+
+
+def install_process_hooks() -> None:
+    """Add the ``engine.gc`` hook and the compile listener, once per
+    process (one listener serves every engine: a listener per engine would
+    pile up across the engines a process builds)."""
+    global _hooks_installed
+    with _hooks_lock:
+        if _hooks_installed:
+            return
+        gc.callbacks.append(_on_gc)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_event_duration)
+        _hooks_installed = True
+
+
 class Tracer:
     """Collects trace events; one tracer serves one engine.
 
-    The engine hands every timestamp in explicitly (read from its
-    injectable clock) — the tracer itself never looks at a clock, which is
-    what makes ManualClock runs reproduce byte-identical traces.
+    Every timestamp is read from the engine's injectable clock: the engine
+    passes it in, and binds ``clock`` (``Engine`` sets it to its own) for
+    the slices ``span`` records -- which is what makes ManualClock runs
+    reproduce byte-identical traces.
     """
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
+        self.clock = None       # bound by the engine the tracer serves
         self.events: list[dict] = []
         self._threads: dict[tuple[int, int], str] = {}
         self._processes: dict[int, str] = {PID_ENGINE: "engine",
@@ -91,27 +207,12 @@ class Tracer:
             "pid": pid, "tid": tid, "ts": _us(t), "args": dict(args)})
 
     # ---------------------------------------------------------- engine hooks
-    def step_span(self, t0: float, t1: float, **args):
-        self.complete("step", CAT_STEP, PID_ENGINE, TID_STEPS, t0, t1,
-                      **args)
-
     def prefill_span(self, rid: int, t0: float, t1: float, **args):
         """Prefill work slice on the engine track (chunk/bucket + page
         annotations) — the request's own PREFILL lifecycle span covers
         queue-exit to first token on its request track."""
         self.complete("prefill", CAT_STEP, PID_ENGINE, TID_STEPS, t0, t1,
                       rid=rid, **args)
-
-    # speculative decoding (DESIGN.md §16): the two halves of one verify
-    # step on the engine track — proposal (speculator host/draft-model
-    # work) and the batched verify forward + accept/emit
-    def propose_span(self, t0: float, t1: float, **args):
-        self.complete("propose", CAT_STEP, PID_ENGINE, TID_STEPS, t0, t1,
-                      **args)
-
-    def verify_span(self, t0: float, t1: float, **args):
-        self.complete("verify", CAT_STEP, PID_ENGINE, TID_STEPS, t0, t1,
-                      **args)
 
     def request_state(self, rid: int, state: str, t: float, **args):
         """Move a request's lifecycle track to ``state`` at time ``t``:
@@ -245,10 +346,3 @@ def validate_trace(obj) -> list[str]:
                         f"thread_name metadata")
     return problems
 
-
-def load_trace(path: str) -> dict:
-    with open(path) as f:
-        return json.load(f)
-
-
-NULL_TRACER: Optional[Tracer] = None   # the documented "tracing off" value

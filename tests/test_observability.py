@@ -24,7 +24,7 @@ from repro.serving.clock import ManualClock
 from repro.serving.engine import Engine
 from repro.serving.http_api import make_server
 from repro.serving.sampler import SamplingParams
-from repro.serving.tracing import (PID_ENGINE, PID_REQUESTS, Tracer,
+from repro.serving.tracing import (PID_ENGINE, PID_REQUESTS, Tracer, span,
                                    validate_trace)
 from tests.test_serving_faults import _drain, _prompts
 
@@ -122,10 +122,14 @@ def test_null_registry_is_inert():
 
 # -------------------------------------------------------------------- tracer
 def test_tracer_spans_and_validation():
+    clk = ManualClock(2.0)
     tr = Tracer()
+    tr.clock = clk
     tr.request_state(3, "QUEUED", 1.0, prompt_len=4)
     tr.request_state(3, "RUNNING", 2.0)
-    tr.step_span(2.0, 2.5, step=0, batch=1)
+    with span("engine.step", tr, step=0) as sp:
+        clk.advance(0.5)
+        sp.set(batch=1)
     tr.fault_instant("stall", 2.25)
     tr.request_end(3, "stop", 4.0, tokens=6)
     d = tr.to_dict()
@@ -135,6 +139,9 @@ def test_tracer_spans_and_validation():
     assert queued == {"name": "QUEUED", "cat": "request", "ph": "X",
                       "pid": PID_REQUESTS, "tid": 3, "ts": 1e6, "dur": 1e6,
                       "args": {"prompt_len": 4}}
+    step = next(e for e in evs if e["name"] == "engine.step")
+    assert (step["ts"], step["dur"], step["args"]) == (
+        2e6, 5e5, {"step": 0, "batch": 1})
     assert any(e["name"] == "fault:stall" and e["pid"] == PID_ENGINE
                for e in evs)
     assert any(e["name"] == "finish" and e["args"]["reason"] == "stop"
@@ -142,7 +149,8 @@ def test_tracer_spans_and_validation():
     # disabled tracer records nothing
     off = Tracer(enabled=False)
     off.request_state(1, "QUEUED", 0.0)
-    off.step_span(0.0, 1.0)
+    with span("engine.step", off):
+        pass
     assert off.events == []
 
 
@@ -288,7 +296,7 @@ def test_trace_is_byte_deterministic_and_complete(small_lm):
     assert "offload" in names and "restore" in names
     finishes = [e for e in evs if e["name"] == "finish"]
     assert {e["args"]["reason"] for e in finishes} == {"length", "shed"}
-    steps = [e for e in evs if e["name"] == "step"]
+    steps = [e for e in evs if e["name"] == "engine.step"]
     assert steps and all("free_pages" in e["args"] for e in steps)
     assert all(e["pid"] == PID_ENGINE for e in steps)
     prefills = [e for e in evs if e["name"] == "prefill"]

@@ -15,6 +15,7 @@ CPU, and the compile cache goes where the environment or the checkout says.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,10 +125,9 @@ def test_paged_prefill_compiles(spec, page_size, dtype):
              spec((2,)), spec((2,)), **scales)
 
 
-@pytest.mark.parametrize("width", [1, 128])
-def test_fused_step_layer_compiles(spec, width):
-    """One layer of the engine's fused step program — decode (width 1) and
-    a prefill chunk — with int4 params and a bf16 page pool."""
+def _compile_fused_step(spec, width):
+    """One layer of the engine's fused step program at qwen3-4b widths,
+    int4 params and a bf16 page pool, for a batch of 8 rows x ``width``."""
     from repro.serving.engine import Engine
     cfg = dataclasses.replace(QWEN3_4B, num_layers=1)
     model = build_model(cfg)
@@ -143,10 +143,47 @@ def test_fused_step_layer_compiles(spec, width):
     step = functools.partial(Engine._fused_step_impl, model, kernels,
                              all_greedy=True)
     b = 8
-    _compile(step, params, spec((b, width)), spec((b,)), spec((b, 0)),
-             spec((b,)), spec((b,), jnp.bool_), cache, spec((b,)),
-             spec((b, 16)), spec((b,), jnp.bool_),
-             None, None, None, None, None, None)
+    return _compile(step, params, spec((b, width)), spec((b,)),
+                    spec((b, 0)), spec((b,)), spec((b,), jnp.bool_), cache,
+                    spec((b,)), spec((b, 16)), spec((b,), jnp.bool_),
+                    None, None, None, None, None, None)
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_fused_step_layer_compiles(spec, width):
+    """One layer of the engine's fused step program — decode (width 1) and
+    a prefill chunk — with int4 params and a bf16 page pool."""
+    _compile_fused_step(spec, width)
+
+
+def _kernel_names(compiled) -> set:
+    """Names of the Mosaic kernel ops in a compiled program's HLO."""
+    return {m.group(1) for m in re.finditer(
+        r"%(\w+)\.\d+ = .* custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\"", compiled.as_text())}
+
+
+@pytest.mark.parametrize("width,kernels", [
+    (1, {"gptq_gemv", "paged_attention"}),
+    (128, {"gptq_matmul", "paged_prefill"})])
+def test_fused_step_kernel_op_names(spec, width, kernels):
+    """Each Mosaic kernel is named by its ``pallas_call`` (``name=``), not
+    by the function around it: the device ops a profile shows are
+    ``%gptq_gemv.N`` / ``%gptq_matmul.N`` / ``%paged_attention.N`` /
+    ``%paged_prefill.N``, the names the chip benchmark's kernel classes
+    (``benchmarks/chip/kernels.json``) match."""
+    assert _kernel_names(_compile_fused_step(spec, width)) == kernels
+    # the served GPTQ kernel keeps its name inside a function of another
+    k, n = PROJECTIONS[0]
+
+    def outer(x, qw, s, qz):
+        return gptq_matmul.fused_full_k(
+            x, qw, s, qz, None, group_size=GROUP, bm=8, bn=256, bk=512,
+            out_dtype=jnp.bfloat16, interpret=False, name="gptq_gemv")
+
+    compiled = _compile(outer, spec((8, k), jnp.bfloat16),
+                        *_gptq_operands(spec, k, n))
+    assert _kernel_names(compiled) == {"gptq_gemv"}
 
 
 def test_kernel_lane_resolves_from_backend(monkeypatch):
