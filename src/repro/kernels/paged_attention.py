@@ -9,28 +9,34 @@ substrate is built on, mapped to TPU idiom:
 * **Head-major pools** ``(Hkv, P, page_size, D)``: one kv head's page is a
   ``(page_size, D)`` tile in the last two dimensions, which is what the TPU
   (8, 128) block rule needs (int8 pools: page_size a multiple of 32).
-* **Grid (B, Hkv, n_pages)** with the block table and sequence lengths as
-  *scalar-prefetch* operands: the K/V ``BlockSpec`` index maps read
-  ``block_tables[b, p]`` so each program DMAs exactly one physical page into
-  VMEM — the gather happens in the memory system, never as a materialized
+* **Decode walks only each row's live pages** — grid ``(B,)``, the block
+  table and sequence lengths scalar-prefetched, the pools left in HBM.  A
+  row's program loops over ``cdiv(lengths[b], pages_per_block *
+  page_size)`` blocks of about 128 tokens; each live page of a block is one
+  DMA per pool that brings that page of every kv head (a strided copy)
+  into a double-buffered VMEM scratch, and the next block's DMAs are in
+  flight while the current one is computed.  Pages at or past
+  ``cdiv(lengths[b], page_size)`` are never fetched, and no block past the
+  last live page is computed; inside the last block their slots hold stale
+  data, so both the logits and the value rows past the length are masked.
+  The gather happens as page-granular DMAs, never as a materialized
   (B, L, Hkv, D) copy.
-* **Online softmax over pages** — running max ``m``, normalizer ``l`` and an
-  fp32 output accumulator live in VMEM scratch across the page loop (same
-  scheme as ``flash_attention.py``); one writeback on the last page.
+* **Online softmax over blocks** — running max ``m``, normalizer ``l`` and
+  an fp32 output accumulator per kv head live in VMEM scratch across the
+  block loop (same scheme as ``flash_attention.py``); one writeback at the
+  end of the row.
 * **GQA without head repetition** — the query block for a kv head is its
-  ``rep = H // Hkv`` query heads, shaped (rep, D); logits are (rep, page_size)
+  ``rep = H // Hkv`` query heads, shaped (rep, D); logits are (rep, block)
   so K/V are read once per kv head, never repeated.
-* Pages past a sequence's length (block-table padding points at the null
-  page) still execute structurally but are fully masked, mirroring the
-  flash kernel's masked-tile convention.
 * **Fused int8-KV dequantization** (ISSUE 4) — with ``k_scales``/``v_scales``
   the K/V pools hold int8 payloads and the kernel DMAs the page *plus its
   scales* into VMEM, rescaling inside the online-softmax loop: a floating-
   point copy of the KV cache never exists in HBM.  Scale pools are parallel
   to the page pools — ``(Hkv, P, page_size)`` per-token or ``(Hkv, P)``
-  per-page symmetric scales (``serving/kv_quant.py``); the kernels view
-  them with trailing unit dimensions so a page's scales are one legal
-  block.
+  per-page symmetric scales (``serving/kv_quant.py``).  The prefill kernel
+  views them with trailing unit dimensions so a page's scales are one
+  legal block; the decode kernel reads them as 128-lane rows (a DMA slice
+  must be lane-aligned) and lifts each page's scales out of its row.
 
 * **Chunked paged prefill** (ISSUE 5) — ``paged_prefill`` runs the *whole
   suffix block* of a (possibly prefix-hit) prompt with online softmax
@@ -90,35 +96,10 @@ def _sm_flush(o_ref, m_ref, l_ref, acc_ref):
     o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype).reshape(o_ref.shape)
 
 
-def _page_update(q, k, v, b, o_ref, m_ref, l_ref, acc_ref, len_ref, *,
-                 page_size, scale):
-    """One decode page: q (rep, D); k, v (page_size, D) fp32 in VMEM (already
-    dequantized on the int8 path)."""
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _():
-        _sm_reset(m_ref, l_ref, acc_ref)
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    kpos = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, dimension=1)
-    s = jnp.where(kpos < len_ref[b], s, -jnp.inf)
-    _sm_update(s, v, m_ref, l_ref, acc_ref)
-
-    @pl.when(p == pl.num_programs(2) - 1)
-    def _():
-        _sm_flush(o_ref, m_ref, l_ref, acc_ref)
-
-
-def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, page_size, scale):
-    b = pl.program_id(0)
-    q = q_ref[0, 0].astype(jnp.float32)                  # (rep, D)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (page_size, D)
-    v = v_ref[0, 0].astype(jnp.float32)                  # (page_size, D)
-    _page_update(q, k, v, b, o_ref, m_ref, l_ref, acc_ref, len_ref,
-                 page_size=page_size, scale=scale)
+def _dequant(x, s):
+    """In-VMEM rescale of int8 KV: ``s`` holds the symmetric scales of
+    ``x``'s rows, broadcasting over its last dimension.  Returns fp32."""
+    return x.astype(jnp.float32) * s.astype(jnp.float32)
 
 
 def _dequant_page(k_ref, v_ref, ks_ref, vs_ref):
@@ -126,9 +107,8 @@ def _dequant_page(k_ref, v_ref, ks_ref, vs_ref):
     payload plus its scales — a (page_size, 1) per-token column or a (1, 1)
     per-page scalar, either broadcasting over (page_size, D).  Returns fp32
     k, v; no fp KV is ever materialized in HBM."""
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0].astype(jnp.float32)
-    return k, v
+    return (_dequant(k_ref[0, 0], ks_ref[0, 0]),
+            _dequant(v_ref[0, 0], vs_ref[0, 0]))
 
 
 def _scale_spec_and_views(k_scales, v_scales, page_size, index_map):
@@ -145,15 +125,128 @@ def _scale_spec_and_views(k_scales, v_scales, page_size, index_map):
     return pl.BlockSpec((1, 1, rows, 1), index_map), views
 
 
-def _kernel_quant(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, page_size, scale):
-    """Int8-KV decode variant: dequantization happens inside the online-
-    softmax page loop (see ``_dequant_page``)."""
+# ---------------------------------------------------------------------- decode
+LANES = 128
+# Keys one iteration of the decode kernel's page loop fetches and computes:
+# a block of whole pages, about this many tokens.  On a v5e at qwen3-4b's
+# decode shapes 64, 128 and 256 ran alike (PERF.md §6); 128 wastes
+# less of a short row's last block than 256.
+_DECODE_BLOCK_TOKENS = 128
+
+
+def _lane_rows(scales):
+    """A scale pool as whole 128-lane rows: (Hkv, P, r) or (Hkv, P) ->
+    (Hkv, R, 1, LANES), each head's scales flattened and zero-padded.  A
+    DMA slice must be lane-aligned, and one page's r scales are narrower
+    than a row: the decode kernel fetches the row holding them."""
+    hkv = scales.shape[0]
+    flat = scales.reshape(hkv, -1)
+    flat = jnp.pad(flat, ((0, 0), (0, -flat.shape[1] % LANES)))
+    return flat.reshape(hkv, -1, 1, LANES)
+
+
+def _decode_kernel(bt_ref, len_ref, q_ref, *refs, pages_per_block,
+                   scale_rows, scale):
+    """One row: an online softmax per kv head over the row's live page
+    blocks only.  The pools stay in HBM.  Each live page of a block is one
+    DMA per pool that brings the page of every kv head (and on int8 pools
+    one more per scale pool, the 128-lane rows holding its scales) into one
+    half of a double-buffered VMEM scratch; the next block's DMAs are in
+    flight while the current block is computed.  Pages at or past
+    ``cdiv(length, page_size)`` are never fetched; their slots in the last
+    block hold whatever an earlier block left there, so both the logits and
+    the value rows past the length are masked (a stale row may be
+    non-finite, and 0 * nan is nan).  ``scale_rows`` is the scales per page
+    (page_size or 1), 0 on a floating-point pool."""
+    quant = scale_rows > 0
+    if quant:
+        (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
+         sem, m_ref, l_ref, acc_ref) = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                 (vs_hbm, vs_buf))
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
-    q = q_ref[0, 0].astype(jnp.float32)                  # (rep, D)
-    k, v = _dequant_page(k_ref, v_ref, ks_ref, vs_ref)
-    _page_update(q, k, v, b, o_ref, m_ref, l_ref, acc_ref, len_ref,
-                 page_size=page_size, scale=scale)
+    max_pages = bt_ref.shape[1]
+    _, hkv, _, page_size, d = k_buf.shape
+    block = pages_per_block * page_size
+    # keys past the block table (an overrunning row) are not attended
+    length = jnp.minimum(len_ref[b], max_pages * page_size)
+    live_pages = pl.cdiv(length, page_size)
+    n_blocks = pl.cdiv(live_pages, pages_per_block)
+
+    def page_id(blk, j):
+        return bt_ref[b, jnp.minimum(blk * pages_per_block + j, max_pages - 1)]
+
+    def page_copies(blk, slot, start):
+        """Start (or wait for) the DMAs of block ``blk``'s live pages into
+        buffer ``slot``; one semaphore per slot counts all of them."""
+        for j in range(pages_per_block):
+            @pl.when(blk * pages_per_block + j < live_pages)
+            def _():
+                page = page_id(blk, j)
+                row = page * scale_rows // LANES     # of the scale pools
+                for (hbm, buf), src in zip(pools, (page, page, row, row)):
+                    copy = pltpu.make_async_copy(
+                        hbm.at[:, src], buf.at[slot, :, j], sem.at[slot])
+                    if start:
+                        copy.start()
+                    else:
+                        copy.wait()
+
+    def scale_columns(buf, slot, blk, h):
+        """Head ``h``'s scales of each page as a (page_size, 1) column of its
+        tokens', lifted out of the 128-lane row the DMA brought (a per-page
+        scale repeats down the column): (pages_per_block, page_size, 1)."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (page_size, LANES), 1)
+        tok = jax.lax.broadcasted_iota(jnp.int32, (page_size, LANES), 0)
+        # the lane of token t's scale, past the page's first
+        offset = tok * scale_rows // page_size
+        cols = []
+        for j in range(pages_per_block):
+            first = page_id(blk, j) * scale_rows % LANES
+            row = jnp.broadcast_to(buf[slot, h, j], (page_size, LANES))
+            cols.append(jnp.where(lane == first + offset, row, 0.0)
+                        .sum(axis=1, keepdims=True))
+        return jnp.stack(cols)
+
+    for h in range(hkv):
+        _sm_reset(m_ref.at[h], l_ref.at[h], acc_ref.at[h])
+    page_copies(0, 0, start=True)
+
+    def body(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            page_copies(blk + 1, 1 - slot, start=True)
+
+        page_copies(blk, slot, start=False)
+        kpos = blk * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+        vpos = blk * block + jax.lax.broadcasted_iota(jnp.int32, (block, d), 0)
+        for h in range(hkv):
+            q = q_ref[0, h]                              # (rep, D)
+            if quant:
+                k = _dequant(k_buf[slot, h], scale_columns(ks_buf, slot, blk, h))
+                v = _dequant(v_buf[slot, h], scale_columns(vs_buf, slot, blk, h))
+            else:
+                k, v = k_buf[slot, h], v_buf[slot, h].astype(jnp.float32)
+            k, v = k.reshape(block, d), v.reshape(block, d)
+            if q.dtype != k.dtype:
+                q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+            # bf16 x bf16 products are exact in fp32: Q.K^T takes the
+            # operands as they are and accumulates in fp32
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(kpos < length, s * scale, -jnp.inf)
+            v = jnp.where(vpos < length, v, 0.0)
+            _sm_update(s, v, m_ref.at[h], l_ref.at[h], acc_ref.at[h])
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, body, 0)
+    for h in range(hkv):
+        _sm_flush(o_ref.at[0, h], m_ref.at[h], l_ref.at[h], acc_ref.at[h])
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -169,10 +262,12 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     k_pages/v_pages: (Hkv, P, page_size, D) head-major physical page pools
                    (int8 when ``k_scales``/``v_scales`` are given).
     block_tables : (B, max_pages) int32 — logical page i of sequence b lives
-                   in physical page ``block_tables[b, i]``; padding entries
-                   must point at a valid (e.g. null) page.
+                   in physical page ``block_tables[b, i]``; entries past a
+                   row's length are never read.
     lengths      : (B,) int32 — keys at logical positions < lengths[b] attend
-                   (the just-written decode token included).
+                   (the just-written decode token included); only the
+                   ``cdiv(lengths[b], page_size)`` pages holding them are
+                   fetched.
     k_scales/v_scales: optional symmetric dequant scales parallel to the
                    pools — (Hkv, P, page_size) per-token or (Hkv, P)
                    per-page; dequantization is fused into the page loop.
@@ -187,39 +282,35 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     rep = h // hkv
     max_pages = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    ppb = max(1, min(max_pages, _DECODE_BLOCK_TOKENS // page_size))
 
     qg = q.reshape(b, hkv, rep, d)
-
-    def page_map(b, h, p, bt, ln):
-        return (h, bt[b, p], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, rep, d), lambda b, h, p, bt, ln: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d), page_map),
-        pl.BlockSpec((1, 1, page_size, d), page_map),
-    ]
+    row_spec = pl.BlockSpec((1, hkv, rep, d), lambda b, bt, ln: (b, 0, 0, 0))
     inputs = [qg, k_pages, v_pages]
-    if k_scales is None:
-        kernel = functools.partial(_kernel, page_size=page_size, scale=scale)
-    else:
-        scale_spec, views = _scale_spec_and_views(k_scales, v_scales,
-                                                  page_size, page_map)
-        in_specs += [scale_spec, scale_spec]
-        inputs += views
-        kernel = functools.partial(_kernel_quant, page_size=page_size,
-                                   scale=scale)
+    buffers = [pltpu.VMEM((2, hkv, ppb, page_size, d), pool.dtype)
+               for pool in (k_pages, v_pages)]
+    scale_rows = 0
+    if k_scales is not None:
+        scale_rows = 1 if k_scales.ndim == 2 else page_size
+        assert LANES % scale_rows == 0, (
+            f"int8 decode needs a page_size dividing {LANES}: {page_size}")
+        inputs += [_lane_rows(k_scales), _lane_rows(v_scales)]
+        buffers += [pltpu.VMEM((2, hkv, ppb, 1, LANES), s.dtype)
+                    for s in (k_scales, v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rep, d),
-                               lambda b, h, p, bt, ln: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((rep,), jnp.float32),
-                        pltpu.VMEM((rep,), jnp.float32),
-                        pltpu.VMEM((rep, d), jnp.float32)],
+        grid=(b,),
+        in_specs=[row_spec] + [pl.BlockSpec(memory_space=pltpu.HBM)] * (
+            len(inputs) - 1),
+        out_specs=row_spec,
+        scratch_shapes=buffers + [pltpu.SemaphoreType.DMA((2,)),
+                                  pltpu.VMEM((hkv, rep), jnp.float32),
+                                  pltpu.VMEM((hkv, rep), jnp.float32),
+                                  pltpu.VMEM((hkv, rep, d), jnp.float32)],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_decode_kernel, pages_per_block=ppb,
+                          scale_rows=scale_rows, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
         interpret=interpret,

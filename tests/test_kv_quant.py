@@ -114,15 +114,30 @@ def _random_paged(rng, b, h, hkv, d, pages, ps, maxp, lens):
     return q, kp, vp, bt, jnp.asarray(lens, jnp.int32)
 
 
-@pytest.mark.parametrize("granularity,h,hkv", [
-    ("token", 8, 2), ("token", 4, 4), ("page", 8, 2)])
-def test_paged_attention_int8_matches_ref(granularity, h, hkv):
+# (granularity, h, hkv, page_size, max_pages, lengths): as
+# test_paged.py's DECODE_CASES — one token, one page, one block, one past
+# a block, the full table; rep 4 and 6; tables not a whole number of blocks
+@pytest.mark.parametrize("granularity,h,hkv,ps,maxp,lens", [
+    pytest.param("token", 8, 2, 8, 5, [1, 11, 40], id="token-8-2"),
+    pytest.param("token", 4, 4, 8, 5, [1, 11, 40], id="token-4-4"),
+    pytest.param("page", 8, 2, 8, 5, [1, 11, 40], id="page-8-2"),
+    pytest.param("token", 12, 2, 32, 5, [1, 32, 128, 129, 160],
+                 id="token-rep6-page32-5"),
+    pytest.param("page", 12, 2, 32, 17, [1, 32, 128, 129, 544],
+                 id="page-rep6-page32-17"),
+    pytest.param("token", 8, 2, 8, 17, [1, 8, 128, 129, 136],
+                 id="token-rep4-page8-17"),
+])
+def test_paged_attention_int8_matches_ref(granularity, h, hkv, ps, maxp,
+                                          lens):
     """The fused-dequant kernel path agrees with the (materializing) oracle
-    at both scale granularities."""
+    at both scale granularities, and never reads the scales of a page past
+    a row's length: nan there leaves the output unchanged."""
     rng = np.random.default_rng(0)
-    b, d, pages, ps, maxp = 3, 64, 17, 8, 5
+    b, d = len(lens), 64
+    pages = b * maxp + 2
     q, kp, vp, bt, lens = _random_paged(rng, b, h, hkv, d, pages, ps, maxp,
-                                        [1, 11, maxp * ps])
+                                        lens)
     axes = (-1,) if granularity == "token" else (2, 3)
     kq, ks = KQ.quantize(kp, axes=axes)
     vq, vs = KQ.quantize(vp, axes=axes)
@@ -133,6 +148,13 @@ def test_paged_attention_int8_matches_ref(granularity, h, hkv):
     # and the quantized result stays near the fp oracle (int8 error only)
     base = paged_attention_ref(q, kp, vp, bt, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(base), atol=0.05)
+    used = {int(bt[i, j]) for i in range(b)
+            for j in range(-(-int(lens[i]) // ps))}
+    dead = jnp.asarray([p for p in range(pages) if p not in used])
+    out2 = paged_attention(q, kq, vq, bt, lens,
+                           k_scales=ks.at[:, dead].set(jnp.nan),
+                           v_scales=vs.at[:, dead].set(jnp.nan))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
 
 
 def test_paged_attention_int8_requires_both_scales():

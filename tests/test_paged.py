@@ -34,12 +34,29 @@ def _random_paged(rng, b, h, hkv, d, pages, ps, maxp, lens):
     return q, kp, vp, bt, jnp.asarray(lens, jnp.int32)
 
 
-@pytest.mark.parametrize("h,hkv", [(8, 2), (4, 4)])
-def test_paged_attention_matches_ref(h, hkv):
+# (h, hkv, page_size, max_pages, lengths).  The kernel walks blocks of
+# 128 // page_size pages: page 8 -> 16-page blocks, page 32 -> 4-page
+# blocks, so the edge cases are one token, exactly one page, exactly one
+# block, one past a block and the full table, with tables that are not a
+# whole number of blocks (5, 17 pages).
+DECODE_CASES = [
+    pytest.param(8, 2, 8, 5, [1, 11, 40], id="8-2"),
+    pytest.param(4, 4, 8, 5, [1, 11, 40], id="4-4"),
+    pytest.param(8, 2, 8, 17, [1, 8, 128, 129, 136], id="rep4-page8-17"),
+    pytest.param(12, 2, 8, 17, [1, 8, 128, 129, 136], id="rep6-page8-17"),
+    pytest.param(8, 2, 32, 5, [1, 32, 128, 129, 160], id="rep4-page32-5"),
+    pytest.param(12, 2, 32, 17, [1, 32, 128, 129, 544],
+                 id="rep6-page32-17"),
+]
+
+
+@pytest.mark.parametrize("h,hkv,ps,maxp,lens", DECODE_CASES)
+def test_paged_attention_matches_ref(h, hkv, ps, maxp, lens):
     rng = np.random.default_rng(0)
-    b, d, pages, ps, maxp = 3, 64, 17, 8, 5
+    b, d = len(lens), 64
+    pages = b * maxp + 2
     q, kp, vp, bt, lens = _random_paged(rng, b, h, hkv, d, pages, ps, maxp,
-                                        [1, 11, maxp * ps])
+                                        lens)
     out = paged_attention(q, kp, vp, bt, lens)
     ref = paged_attention_ref(q, kp, vp, bt, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -65,19 +82,23 @@ def test_paged_attention_matches_contiguous_flash_ref():
 
 def test_paged_attention_ignores_pages_past_length():
     """Block-table padding (null page) and garbage in unowned pages must not
-    leak into the output: clobbering every page past each sequence's length
-    with huge values leaves the result unchanged."""
+    leak into the output: the kernel never fetches a page past a row's
+    length, so filling every such page with nan leaves the result finite
+    and unchanged — over one block and over several (32-page blocks of
+    4-token pages; the last row spans two)."""
     rng = np.random.default_rng(2)
-    b, h, hkv, d, pages, ps, maxp = 2, 4, 2, 16, 9, 4, 4
+    b, h, hkv, d, ps, maxp = 3, 4, 2, 16, 4, 40
+    pages = b * maxp + 2
     q, kp, vp, bt, lens = _random_paged(rng, b, h, hkv, d, pages, ps, maxp,
-                                        [5, 9])
+                                        [5, 9, 130])
     out = paged_attention(q, kp, vp, bt, lens)
     used = {int(bt[i, j]) for i in range(b)
             for j in range(-(-int(lens[i]) // ps))}
-    clobber = [p for p in range(pages) if p not in used]
-    kp2 = kp.at[:, jnp.asarray(clobber)].set(1e9)
-    vp2 = vp.at[:, jnp.asarray(clobber)].set(-1e9)
+    clobber = jnp.asarray([p for p in range(pages) if p not in used])
+    kp2 = kp.at[:, clobber].set(jnp.nan)
+    vp2 = vp.at[:, clobber].set(jnp.nan)
     out2 = paged_attention(q, kp2, vp2, bt, lens)
+    assert np.isfinite(np.asarray(out2)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2))
 
 

@@ -99,21 +99,28 @@ def test_gptq_matmul_compiles(spec, k, n):
              spec((512, k), jnp.bfloat16), qw, s, qz)
 
 
-def _pools(spec, page_size, dtype, pages=257):
+def _pools(spec, page_size, dtype, pages=257, per_page=False):
     pool = spec((HKV, pages, page_size, D), dtype)
     if dtype != jnp.int8:
         return pool, {}
-    scales = spec((HKV, pages, page_size), jnp.float32)
+    scales = spec((HKV, pages) if per_page else (HKV, pages, page_size),
+                  jnp.float32)
     return pool, {"k_scales": scales, "v_scales": scales}
 
 
-@pytest.mark.parametrize("page_size,dtype", POOLS)
-def test_paged_attention_compiles(spec, page_size, dtype):
-    pool, scales = _pools(spec, page_size, dtype)
+@pytest.mark.parametrize("page_size,dtype,per_page,rows,pages,table", [
+    pytest.param(16, jnp.bfloat16, False, 8, 257, 32, id="16-bfloat16"),
+    pytest.param(32, jnp.int8, False, 8, 257, 32, id="32-int8"),
+    pytest.param(32, jnp.int8, True, 8, 257, 32, id="32-int8-per-page"),
+    # qwen3-4b.decode-long: 32 rows, its 2504-page pool, a 4096-token table
+    pytest.param(16, jnp.bfloat16, False, 32, 2504, 256, id="decode-long")])
+def test_paged_attention_compiles(spec, page_size, dtype, per_page, rows,
+                                  pages, table):
+    pool, scales = _pools(spec, page_size, dtype, pages, per_page)
     _compile(functools.partial(paged_attention.paged_attention,
                                interpret=False),
-             spec((8, H, D), jnp.bfloat16), pool, pool, spec((8, 32)),
-             spec((8,)), **scales)
+             spec((rows, H, D), jnp.bfloat16), pool, pool,
+             spec((rows, table)), spec((rows,)), **scales)
 
 
 @pytest.mark.parametrize("page_size,dtype", POOLS)
