@@ -11,7 +11,9 @@ standard output when the measured window opens and closes, ``t`` on
 ``time.monotonic()`` (one clock for every process of the machine), and
 writes every request's record to ``result.json``:
 
-* closed loop: the window opens once every client's first request has its
+* closed loop: the clients send their first requests one at a time,
+  ``STAGGER_S`` apart, so the server admits them in the same order in
+  every run; the window opens once every client's first request has its
   first token, and lasts ``seconds``; clients send nothing new after it;
 * open loop: requests go out at their planned times from the start, the
   window opens ``lead_s`` after the start, and nothing is sent after it.
@@ -32,6 +34,8 @@ from chipbench import traffic as T
 
 READ_TIMEOUT_S = 900.0
 OPEN_TIMEOUT_S = 600.0
+STAGGER_S = 0.02
+STAGGER_TIMEOUT_S = 10.0
 
 
 class Requests:
@@ -45,7 +49,8 @@ class Requests:
         self._lock = threading.Lock()
         self._hung_up = False
 
-    def send(self, req: dict, rec: dict, on_first=None) -> None:
+    def send(self, req: dict, rec: dict, on_first=None,
+             on_sent=None) -> None:
         body = json.dumps({"prompt": req["prompt"],
                            "max_tokens": req["max_tokens"],
                            "temperature": 0.0, "ignore_eos": True,
@@ -68,6 +73,8 @@ class Requests:
                 raise ConnectionAbortedError("hung up before sending")
             conn.request("POST", "/v1/completions", body,
                          {"Content-Type": "application/json"})
+            if on_sent is not None:
+                on_sent()
             resp = conn.getresponse()
             rec["http"] = resp.status
             if resp.status != 200:
@@ -151,18 +158,24 @@ def run_closed(reqs: Requests, plan: dict, traffic: dict, seconds: float):
             if waiting["n"] == 0:
                 started.set()
 
-    def client(ci: int):
+    def client(ci: int, sent: threading.Event):
         for ri, req in enumerate(clients[ci]):
             if stop.is_set():
                 return
             reqs.send(req, _record(req, client=ci, index=ri),
-                      first_token if ri == 0 else None)
+                      first_token if ri == 0 else None,
+                      sent.set if ri == 0 else None)
         print(f"client {ci} ran out of requests", file=sys.stderr)
 
-    threads = [threading.Thread(target=client, args=(i,), daemon=True)
-               for i in range(len(clients))]
-    for th in threads:
+    threads = []
+    for i in range(len(clients)):
+        sent = threading.Event()
+        th = threading.Thread(target=client, args=(i, sent), daemon=True)
+        threads.append(th)
         th.start()
+        # the server takes the first requests in the clients' order
+        sent.wait(STAGGER_TIMEOUT_S)
+        time.sleep(STAGGER_S)
     if not started.wait(OPEN_TIMEOUT_S):
         reqs.hang_up()
         raise RuntimeError(f"{waiting['n']} of {len(clients)} clients had no "

@@ -17,7 +17,7 @@ CLOSED = {"loop": "closed", "clients": 4, "requests_per_client": 20,
                      "min": 8, "max": 60},
           "output": {"dist": "uniform", "min": 8, "max": 16},
           "first_output": {"dist": "uniform", "min": 2, "max": 8}, "drain_s": 0, "trace_s": 2,
-          "check": {"tokens": 30, "max_requests": 8}}
+          "check": {"tokens": 160, "max_requests": 8}}
 OPEN = {"loop": "open", "rate_per_s": 2.0, "lead_s": 2,
         "prompt": {"dist": "lognormal", "median": 20, "sigma": 1.0,
                    "min": 8, "max": 60},

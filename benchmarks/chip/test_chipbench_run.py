@@ -54,8 +54,9 @@ def test_control_is_not_correct(name):
         < compared["control_gap.fp8"], compared
 
 
-def test_token_altered_where_produced_is_not_correct():
-    cell = tiny.cell("qwen3-4b")
+@pytest.mark.parametrize("name", ["qwen3-4b", "nemotron-4-15b"])
+def test_token_altered_where_produced_is_not_correct(name):
+    cell = tiny.cell(name)
     vocab = cell.config["vocab_size"]
 
     def alter_tokens(engine):

@@ -11,6 +11,7 @@ import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+import numpy as np
 import pytest
 
 from chipbench import harness as H
@@ -35,14 +36,10 @@ def _traffic(name):
     return H.load_json(H.BENCH_DIR, "traffic", f"{name}.json")
 
 
-@pytest.mark.parametrize("name", ["decode-long", "doc-prefill", "open"])
-def test_every_seed_offers_the_same_work(name):
-    """Stratified sizes: the multiset of prompt lengths and of output
-    lengths (first answers included) is the same for every seed; the seed
-    changes their order and the token ids."""
-    traffic = _traffic(name)
-    ra, rb = [_requests(T.plan(traffic, s, 1000, 45.0))
-              for s in (1, 2 ** 31 + 9)]
+def _same_multisets(traffic, ra, rb):
+    """The multiset of prompt lengths and of output lengths (first answers
+    included) is the same for both seeds, and every length within the
+    configured range."""
     assert sorted(len(r["prompt"]) for r in ra) == sorted(
         len(r["prompt"]) for r in rb)
     assert sorted(r["max_tokens"] for r in ra) == sorted(
@@ -52,6 +49,104 @@ def test_every_seed_offers_the_same_work(name):
         assert all(2 <= t < 1000 for t in r["prompt"])
         lo, hi = traffic["prompt"]["min"], traffic["prompt"]["max"]
         assert lo <= len(r["prompt"]) <= hi
+
+
+def _sizes(client, key):
+    return [len(r["prompt"]) if key == "prompt" else r["max_tokens"]
+            for r in client]
+
+
+def _per_client(traffic, pa, pb):
+    """Every client's later requests (all of them, for a size without a
+    ``first_`` distribution) hold the strata over their number, on both
+    seeds."""
+    n = traffic["requests_per_client"]
+    for key in ("prompt", "output"):
+        skip = 1 if traffic.get(f"first_{key}") else 0
+        want = sorted(T.stratified(traffic[key], n - skip).tolist())
+        for plan in (pa, pb):
+            for client in plan["clients"]:
+                assert sorted(_sizes(client, key)[skip:]) == want, key
+
+
+def _first_prompts(traffic, pa, pb):
+    """The clients' first prompts are the strata of ``first_prompt`` over
+    the clients, on both seeds."""
+    want = sorted(T.stratified(traffic["first_prompt"],
+                               traffic["clients"]).tolist())
+    for plan in (pa, pb):
+        assert sorted(len(c[0]["prompt"]) for c in plan["clients"]) == want
+
+
+def _ids_only(traffic, pa, pb):
+    """Two seeds give every client the same sizes in the same order, and
+    other token ids: the seed changes no work in the window."""
+    for key in ("prompt", "output"):
+        assert [_sizes(c, key) for c in pa["clients"]] == [
+            _sizes(c, key) for c in pb["clients"]]
+    ra, rb = _requests(pa), _requests(pb)
+    assert all(a["prompt"] != b["prompt"] for a, b in zip(ra, rb))
+
+
+def _follows_distributions(traffic, pa, pb):
+    """The union of all requests follows the configured distributions: the
+    sorted lengths at probability p lie between the distribution's
+    quantiles at p -/+ the coarsest strata's width.  Where a ``first_``
+    distribution differs from the later one, each is held to its own."""
+    c, n = traffic["clients"], traffic["requests_per_client"]
+    for key in ("prompt", "output"):
+        spec, first = traffic[key], traffic.get(f"first_{key}")
+        for plan in (pa, pb):
+            sizes = [_sizes(client, key) for client in plan["clients"]]
+            if first is None or first == spec:
+                groups = [([v for row in sizes for v in row], spec,
+                           min(c, n - (first is not None)))]
+            else:
+                groups = [([row[0] for row in sizes], first, c),
+                          ([v for row in sizes for v in row[1:]], spec,
+                           min(c, n - 1))]
+            for got, want, strata in groups:
+                got = sorted(got)
+                for i, v in enumerate(got):
+                    p = (i + 0.5) / len(got)
+                    lo = T.quantile(want, max(p - 1 / strata, 1e-9))
+                    hi = T.quantile(want, min(p + 1 / strata, 1 - 1e-9))
+                    assert lo <= v <= hi, (key, i, v, lo, hi)
+
+
+def _unpaired(traffic, pa, pb):
+    """A prompt's rank says nothing of its answer's: over the clients'
+    later requests the two lengths' ranks are all but uncorrelated (dealt
+    rank to rank they would correlate fully)."""
+    for plan in (pa, pb):
+        later = [r for c in plan["clients"] for r in c[1:]]
+        ranks = [np.argsort(np.argsort(v, kind="stable"))
+                 for v in ([len(r["prompt"]) for r in later],
+                           [r["max_tokens"] for r in later])]
+        assert abs(np.corrcoef(*ranks)[0, 1]) < 0.3
+
+
+_CLOSED_CASES = {"per_client": _per_client, "first_prompts": _first_prompts,
+                 "ids_only": _ids_only,
+                 "distributions": _follows_distributions,
+                 "unpaired": _unpaired}
+
+
+@pytest.mark.parametrize("name,case", [
+    *(pytest.param(name, None, id=name)
+      for name in ("decode-long", "doc-prefill", "open")),
+    *(pytest.param(name, case, id=f"{name}-{case}")
+      for name in ("decode-long", "doc-prefill") for case in _CLOSED_CASES)])
+def test_every_seed_offers_the_same_work(name, case):
+    """Stratified sizes: every seed offers the same work in another order.
+    Without a case, the multisets over all requests; a closed loop's cases
+    check how they are dealt to its clients."""
+    traffic = _traffic(name)
+    pa, pb = [T.plan(traffic, s, 1000, 45.0) for s in (1, 2 ** 31 + 9)]
+    if case is None:
+        _same_multisets(traffic, _requests(pa), _requests(pb))
+    else:
+        _CLOSED_CASES[case](traffic, pa, pb)
 
 
 def test_open_loop_arrivals():
@@ -66,8 +161,8 @@ def test_open_loop_arrivals():
 
 def test_first_answers_are_staggered():
     """Each client's first answer is a stratum of ``first_output``: all
-    different, shorter than any later answer, and the same set for every
-    seed."""
+    different, shorter than any later answer, and each client's the same
+    on every seed."""
     traffic = _traffic("decode-long")
     firsts = []
     for seed in (3, 2 ** 33 + 1):
@@ -76,7 +171,7 @@ def test_first_answers_are_staggered():
         later = [r["max_tokens"] for c in plan["clients"] for r in c[1:]]
         assert max(firsts[-1]) <= min(later)
     assert len(set(firsts[0])) == len(firsts[0])
-    assert firsts[0] != firsts[1] and sorted(firsts[0]) == sorted(firsts[1])
+    assert firsts[0] == firsts[1]
     spec = traffic["first_output"]
     assert spec["min"] <= min(firsts[0]) and max(firsts[0]) <= spec["max"]
 
@@ -182,7 +277,9 @@ def test_hang_up_ends_open_streams(tmp_path):
 def test_new_files_are_found_by_name(tmp_path):
     """A configuration, traffic mix, cell and metric reader added as new
     files (and named in BENCHMARK.json) are found with no edit to any
-    existing file of the benchmark."""
+    existing file of the benchmark; so are the committed cells', and
+    nemotron-4-15b.doc-prefill is judged on prompt_tok_s, output_tok_s and
+    its own tail of token gaps, with its six prefill per-layer metrics."""
     bench_rel = os.path.relpath(H.BENCH_DIR, ROOT)
     here = tmp_path / bench_rel
     shutil.copytree(H.BENCH_DIR, here,
@@ -210,5 +307,24 @@ def test_new_files_are_found_by_name(tmp_path):
     assert cell.config == cfg and cell.traffic == tiny.CLOSED
     assert cell.limits == {"logit_gap": 0.5}
     assert [m["name"] for m in cell.per_layer] == ["new_metric.x"]
-    assert "itl_p95_ms" in [m["name"] for m in cell.end_to_end]
+    assert "setup_s" in [m["name"] for m in cell.end_to_end]
     assert H.reader("new_metric.x", str(here))(None) == 42.0
+    # every committed cell finds its files, and a reader for each metric
+    committed = H.load_json(ROOT, "BENCHMARK.json")
+    for w in committed["workloads"]:
+        cell = H.Cell.find(w["name"])
+        conf = next(c for c in committed["configs"]
+                    if c["name"] == w["config"])
+        assert cell.config == H.load_json(ROOT, conf["file"])
+        assert cell.traffic == _traffic(w["traffic"])
+        assert cell.limits["logit_gap"] > 0
+        for m in cell.end_to_end + cell.per_layer:
+            assert callable(H.reader(m["name"]))
+    cell = H.Cell.find("nemotron-4-15b.doc-prefill")
+    assert cell.config["name"] == "nemotron-4-15b"
+    assert sorted(m["name"] for m in cell.end_to_end) == [
+        "itl_p95_ms.prefill", "output_tok_s", "prompt_tok_s", "setup_s"]
+    assert sorted(m["name"] for m in cell.per_layer) == sorted(
+        f"{stem}.prefill" for stem in (
+            "step_ms", "step_host_ms", "device_idle_share", "step_mfu",
+            "gptq_roofline", "paged_attn_roofline"))
