@@ -15,24 +15,17 @@ positions of the same sequences.
 """
 from __future__ import annotations
 
-import importlib.util
-import os
 import time
 
 import numpy as np
 
+import chipbench
 from chipbench import weights as W
-
-BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def reference(name: str):
     """The reference module ``references/<name>.py``."""
-    path = os.path.join(BENCH_DIR, "references", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"reference_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return chipbench.load("references", name)
 
 
 def checkable(rec: dict, vocab: int) -> bool:

@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -26,12 +25,13 @@ import time
 
 import numpy as np
 
+import chipbench
 from chipbench import check as C
 from chipbench import program as P
 from chipbench import tracereduce as TR
 from chipbench import weights as W
 
-BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = chipbench.BENCH_DIR
 ROOT = os.path.dirname(os.path.dirname(BENCH_DIR))
 CHILD_SLACK_S = 240.0
 
@@ -94,8 +94,8 @@ class Run:
     steps: list = dataclasses.field(default_factory=list)
 
     @property
-    def projections(self) -> dict:
-        return W.projections(self.config)
+    def family(self):
+        return chipbench.family(self.config)
 
     def in_window(self, t: float) -> bool:
         return self.window[0] <= t <= self.window[1]
@@ -105,13 +105,8 @@ def reader(name: str, bench_dir: str = BENCH_DIR):
     """``read(run)`` of ``metrics/<name>.py``, else of the file named by
     the part of ``name`` before its first dot."""
     for stem in (name, name.split(".")[0]):
-        path = os.path.join(bench_dir, "metrics", f"{stem}.py")
-        if os.path.exists(path):
-            spec = importlib.util.spec_from_file_location(
-                f"metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+        if os.path.exists(os.path.join(bench_dir, "metrics", f"{stem}.py")):
+            return chipbench.load("metrics", stem, bench_dir).read
     raise FileNotFoundError(f"no reader for metric {name!r} under metrics/")
 
 
@@ -124,10 +119,11 @@ def counters(engine) -> dict:
 class StepRecorder:
     """Host spans (``jax.profiler.TraceAnnotation``) around the engine's
     calls, the profiler's start and stop between steps, and the
-    composition of every traced step (``chipbench.work``'s step form).
-    Installed on the instances only, in traced runs only."""
+    composition of every traced step (``chipbench.work``'s step form, and
+    what ``describe``, a family's, adds).  Installed on the instances only,
+    in traced runs only."""
 
-    def __init__(self, engine, trace_dir: str):
+    def __init__(self, engine, trace_dir: str, describe=None):
         import jax
         self.eng = engine
         self.trace_dir = trace_dir
@@ -149,6 +145,8 @@ class StepRecorder:
                 chunks = plan(budget, reserve=reserve)
             if self.state == "tracing":
                 self.steps.append(self._describe(chunks))
+                if describe is not None:
+                    describe(self.eng, self.steps[-1])
             return chunks
 
         engine.step = traced_step
@@ -307,7 +305,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if fault is not None:
         fault(engine)
     workdir = tempfile.mkdtemp(prefix="chipbench-")
-    recorder = (StepRecorder(engine, os.path.join(workdir, "trace"))
+    fam = chipbench.family(cfg)
+    recorder = (StepRecorder(engine, os.path.join(workdir, "trace"),
+                             getattr(fam, "describe", None))
                 if trace else None)
     server = make_server(engine, port=0, model_name=cfg["name"])
     if recorder is not None:
@@ -349,7 +349,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if recorder is not None:
         events = TR.load(recorder.trace_dir) if recorder.state == "done" \
             else []
-        run.trace = TR.reduce(events, load_json(BENCH_DIR, "kernels.json"))
+        run.trace = TR.reduce(events, TR.classes(fam))
         run.steps = recorder.steps
         if run.trace is not None:
             device.update(busy_s=run.trace["busy_s"],

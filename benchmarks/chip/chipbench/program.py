@@ -11,25 +11,15 @@ from __future__ import annotations
 
 import dataclasses
 
-# configuration file key -> repro ModelConfig field
-_FIELDS = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
-    "qk_norm": "qk_norm", "norm_type": "norm_type", "torch_dtype": "dtype",
-}
-_ACT = {"silu": "swiglu", "relu2": "sq_relu"}
+import chipbench
 
 
 def model_config(cfg: dict):
     """The program's ``ModelConfig`` for a configuration file: its registry
-    entry ``cfg["arch"]`` with every size the file gives."""
+    entry ``cfg["arch"]`` with the family's fields for the file."""
     from repro.configs import get_config
-    fields = {f: cfg[k] for k, f in _FIELDS.items()}
-    fields["act"] = _ACT[cfg["hidden_act"]]
-    return dataclasses.replace(get_config(cfg["arch"]), **fields)
+    return dataclasses.replace(get_config(cfg["arch"]),
+                               **chipbench.family(cfg).model_fields(cfg))
 
 
 def _quantized(w: dict, group: int):
@@ -41,22 +31,25 @@ def _quantized(w: dict, group: int):
                            shape=(k, n), group_size=group)
 
 
-def program_params(weights: dict, model, group: int):
+def program_params(cfg: dict, weights: dict, model):
     """The program's parameter tree holding the benchmark's arrays (no
-    copies), checked leaf by leaf against the shapes and dtypes the program
-    expects."""
+    copies; the family's ``program_leaf`` maps each path), checked leaf by
+    leaf against the shapes, dtypes and group size the program expects."""
     import jax
     from repro.core.gptq import GPTQConfig, QuantizedLinear
     from repro.core.quantize_model import abstract_quantized_params
+    group = cfg["quantization"]["group_size"]
+    leaf_of = chipbench.family(cfg).program_leaf
     abstract = abstract_quantized_params(model.abstract_params(),
                                          GPTQConfig(group_size=group))
-    layers = weights["layers"]
-    norms = {"norm1": "attn_norm", "norm2": "mlp_norm"}
 
     def leaf(path, want):
         parts = [str(getattr(e, "key", getattr(e, "name", e))) for e in path]
+        got = leaf_of(parts, weights)
+        if got is None:
+            raise ValueError(f"no benchmark weight for {'/'.join(parts)}")
         if isinstance(want, QuantizedLinear):
-            got = _quantized(layers[parts[-2]], group)
+            got = _quantized(got, group)
             if (got.shape, got.group_size) != (want.shape, want.group_size):
                 raise ValueError(f"{'/'.join(parts)}: {got.shape} g"
                                  f"{got.group_size}, program wants "
@@ -64,18 +57,6 @@ def program_params(weights: dict, model, group: int):
             arrays = [(got.qweight, want.qweight), (got.scales, want.scales),
                       (got.qzeros, want.qzeros)]
         else:
-            if parts == ["embed", "embedding"]:
-                got = weights["embed"]
-            elif parts == ["head", "w"]:
-                got = weights["head"]
-            elif parts[0] == "final_norm":
-                got = weights["final_norm"][parts[1]]
-            elif parts[0] == "group0" and parts[1] in norms:
-                got = layers[norms[parts[1]]][parts[2]]
-            elif parts[:2] == ["group0", "attn"] and parts[2].endswith("norm"):
-                got = layers[parts[2]]
-            else:
-                raise ValueError(f"no benchmark weight for {'/'.join(parts)}")
             arrays = [(got, want)]
         for a, w in arrays:
             if a.shape != w.shape or a.dtype != w.dtype:
@@ -95,8 +76,7 @@ def build_engine(cfg: dict, weights: dict, seed: int):
     from repro.serving.engine import Engine
     model = build_model(model_config(cfg))
     eng_cfg = cfg["engine"]
-    params = program_params(weights, model,
-                            cfg["quantization"]["group_size"])
+    params = program_params(cfg, weights, model)
     engine = Engine(model, params, EngineConfig(
         batch_slots=eng_cfg["batch_slots"], max_len=eng_cfg["max_len"],
         eos_id=-1, cache="paged", page_size=eng_cfg["page_size"],

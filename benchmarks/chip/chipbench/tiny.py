@@ -1,15 +1,13 @@
 """Small stand-ins of the committed cells, for the CPU tests: the same
-configuration files and traffic kinds, cut to a few layers, narrow widths
-and a 512-token vocabulary, with a few slots and short requests."""
+configuration files and traffic kinds, cut to the family's ``TINY`` sizes
+(a few layers, narrow widths), with a few slots and short requests."""
 from __future__ import annotations
 
 import os
 
+import chipbench
 from chipbench.harness import BENCH_DIR, Cell, load_json
 
-SIZES = dict(num_hidden_layers=2, hidden_size=128, num_attention_heads=4,
-             num_key_value_heads=2, head_dim=32, intermediate_size=256,
-             vocab_size=4096)
 ENGINE = dict(batch_slots=4, max_len=256, max_step_tokens=64,
               page_pool_gib=0.01)
 CLOSED = {"loop": "closed", "clients": 4, "requests_per_client": 20,
@@ -30,7 +28,7 @@ OPEN = {"loop": "open", "rate_per_s": 2.0, "lead_s": 2,
 def config(name: str) -> dict:
     """Configuration file ``configs/<name>.json`` at the small sizes."""
     cfg = load_json(BENCH_DIR, "configs", f"{name}.json")
-    cfg.update(SIZES)
+    cfg.update(chipbench.family(cfg).TINY)
     cfg["engine"] = {**cfg["engine"], **ENGINE}
     return cfg
 

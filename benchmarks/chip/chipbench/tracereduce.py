@@ -15,8 +15,11 @@ operation, labelled by the innermost host span around its middle.
 from __future__ import annotations
 
 import glob
+import json
 import os
 import re
+
+import chipbench
 
 SPAN_PREFIXES = ("engine.", "worker.", "harness.")
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
@@ -95,6 +98,18 @@ def classify(name: str, classes: dict) -> str | None:
         if any(re.search(p, name) for p in patterns):
             return cls
     return None
+
+
+def classes(family) -> dict:
+    """``kernels.json``'s kernel classes and the family's ``KERNELS``
+    (class -> regexes over an operation's short name), none named twice."""
+    with open(os.path.join(chipbench.BENCH_DIR, "kernels.json")) as f:
+        table = json.load(f)
+    extra = getattr(family, "KERNELS", {})
+    twice = table.keys() & extra.keys()
+    if twice:
+        raise ValueError(f"kernel classes named twice: {sorted(twice)}")
+    return {**table, **extra}
 
 
 def reduce(events: list[dict], classes: dict) -> dict | None:

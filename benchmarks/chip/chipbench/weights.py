@@ -4,8 +4,9 @@ The benchmark makes the weights, hands them to the program, and lets the
 plain reference draw the very same values again, one layer at a time, so
 the reference takes nothing that the program has made.
 
-Every leaf has its own key: ``fold_in(seed key, crc32(name))`` and, for a
-per-layer leaf, ``fold_in(that, layer)``.  The stacked draw vmaps the
+The family draws the leaves.  Every leaf has its own key: ``fold_in(seed
+key, crc32(name))`` and, for a per-layer leaf, ``fold_in(that, layer)``
+(``layer`` counted over the whole model).  The stacked draw vmaps the
 per-layer draw over the layer index, which gives bit-identical values.
 
 Projections are GPTQ int4 in the AutoGPTQ layout (group ``g`` along K):
@@ -28,6 +29,8 @@ import zlib
 import jax
 import jax.numpy as jnp
 
+import chipbench
+
 NIBBLE_STD = math.sqrt((16 ** 2 - 1) / 12)      # std of q - 7.5, q ~ U{0..15}
 _SHIFTS = tuple(4 * j for j in range(8))
 
@@ -41,35 +44,6 @@ def seed_key(seed: int):
 
 def leaf_key(key, name: str):
     return jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-
-
-def projections(cfg: dict) -> dict[str, tuple[int, int]]:
-    """(K, N) of every int4 projection of one layer."""
-    d, hd = cfg["hidden_size"], cfg["head_dim"]
-    h, hkv, ff = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["intermediate_size"])
-    shapes = {"wq": (d, h * hd), "wk": (d, hkv * hd), "wv": (d, hkv * hd),
-              "wo": (h * hd, d)}
-    if gated(cfg):
-        shapes["w_gate"] = (d, ff)
-    shapes["w_up"] = (d, ff)
-    shapes["w_down"] = (ff, d)
-    return shapes
-
-
-def gated(cfg: dict) -> bool:
-    """SwiGLU (gate and up) or a plain activation on up alone."""
-    act = cfg["hidden_act"]
-    if act not in ("silu", "relu2"):
-        raise ValueError(f"unknown hidden_act {act!r}")
-    return act == "silu"
-
-
-def layernorm(cfg: dict) -> bool:
-    kind = cfg["norm_type"]
-    if kind not in ("rmsnorm", "layernorm"):
-        raise ValueError(f"unknown norm_type {kind!r}")
-    return kind == "layernorm"
 
 
 def pack_cols(z):
@@ -92,7 +66,7 @@ def draw_int4(key, k: int, n: int, group: int) -> dict:
     return {"qweight": qweight, "scales": scales, "qzeros": pack_cols(zeros)}
 
 
-def _norm(key, d: int, with_bias: bool) -> dict:
+def draw_norm(key, d: int, with_bias: bool) -> dict:
     ks, kb = jax.random.split(key)
     out = {"scale": jax.random.uniform(ks, (d,), jnp.float32, 0.9, 1.1)
            .astype(jnp.bfloat16)}
@@ -102,63 +76,37 @@ def _norm(key, d: int, with_bias: bool) -> dict:
     return out
 
 
-def draw_layer(cfg: dict, key, layer):
-    """One layer's weights (``layer`` may be traced)."""
-    group = cfg["quantization"]["group_size"]
-    ln = layernorm(cfg)
-    out = {"attn_norm": _norm(jax.random.fold_in(leaf_key(key, "attn_norm"),
-                                                 layer),
-                              cfg["hidden_size"], ln),
-           "mlp_norm": _norm(jax.random.fold_in(leaf_key(key, "mlp_norm"),
-                                                layer),
-                             cfg["hidden_size"], ln)}
-    if cfg["qk_norm"]:
-        for name in ("q_norm", "k_norm"):
-            k = jax.random.fold_in(leaf_key(key, name), layer)
-            out[name] = jax.random.uniform(
-                k, (cfg["head_dim"],), jnp.float32, 0.9, 1.1
-            ).astype(jnp.bfloat16)
-    for name, (k, n) in projections(cfg).items():
-        out[name] = draw_int4(jax.random.fold_in(leaf_key(key, name), layer),
-                              k, n, group)
-    return out
-
-
-def draw_top(cfg: dict, key) -> dict:
-    """Embedding, final norm and (untied) output head."""
-    v, d = cfg["vocab_size"], cfg["hidden_size"]
-    out = {"embed": (0.02 * jax.random.normal(
-               leaf_key(key, "embed"), (v, d), jnp.bfloat16)
-           ).astype(jnp.bfloat16),
-           "final_norm": _norm(leaf_key(key, "final_norm"), d,
-                               layernorm(cfg))}
-    if not cfg["tie_word_embeddings"]:
-        out["head"] = (d ** -0.5 * jax.random.normal(
-            leaf_key(key, "head"), (d, v), jnp.bfloat16)).astype(jnp.bfloat16)
-    return out
-
-
 def draw_model(cfg: dict, seed: int) -> dict:
-    """Every weight of the model, layers stacked on a leading axis, drawn
-    on the default device in one jitted call."""
+    """Every weight of the model, drawn on the default device in one jitted
+    call, each layer group stacked on a leading axis as ``group<i>``."""
+    fam = chipbench.family(cfg)
+
     def build(key):
-        layers = jax.vmap(lambda i: draw_layer(cfg, key, i))(
-            jnp.arange(cfg["num_hidden_layers"]))
-        return {**draw_top(cfg, key), "layers": layers}
+        out = fam.draw_top(cfg, key)
+        for g, (first, count) in enumerate(fam.groups(cfg)):
+            out[f"group{g}"] = jax.vmap(
+                lambda i: fam.draw_layer(cfg, key, i, g))(
+                    jnp.arange(first, first + count))
+        return out
 
     return jax.jit(build)(seed_key(seed))
 
 
 def layer_drawer(cfg: dict, seed: int):
     """``fn(i)``: layer ``i``'s weights, the same values ``draw_model``
-    stacks — what the reference reads one layer at a time.  The key is an
-    argument, not a constant of the program: a constant key would let the
-    compiler fold the whole draw at compile time, and give every seed a
-    program of its own."""
+    stacks, whatever group holds it — what the reference reads one layer
+    at a time.  The key is an argument, not a constant of the program: a
+    constant key would let the compiler fold the whole draw at compile
+    time, and give every seed a program of its own."""
+    fam = chipbench.family(cfg)
+    group_of = [g for g, (_, count) in enumerate(fam.groups(cfg))
+                for _ in range(count)]
     key = seed_key(seed)
-    fn = jax.jit(lambda k, i: draw_layer(cfg, k, i))
-    return lambda i: fn(key, jnp.int32(i))
+    fn = jax.jit(lambda k, i, g: fam.draw_layer(cfg, k, i, g),
+                 static_argnums=2)
+    return lambda i: fn(key, jnp.int32(i), group_of[i])
 
 
 def top_drawer(cfg: dict, seed: int) -> dict:
-    return jax.jit(lambda k: draw_top(cfg, k))(seed_key(seed))
+    return jax.jit(lambda k: chipbench.family(cfg).draw_top(cfg, k))(
+        seed_key(seed))

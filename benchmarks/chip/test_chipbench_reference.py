@@ -1,14 +1,20 @@
 """The plain reference, the benchmark's weights and the work counts, on the
-CPU at a small size: the engine's prefill-then-decode logits through the
-paged cache agree with the float32 reference, and the work functions give
-the hand-counted numbers."""
+CPU at a small size: every configuration file on disk draws the same layers
+stacked and one by one, runs its registry widths, and gives prefill-then-
+decode logits through the paged cache that agree with the float32
+reference; the dense family's weights are the pinned ones, leaf for leaf;
+and the work functions give the hand-counted numbers."""
 from __future__ import annotations
+
+import hashlib
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import chipbench
 from chipbench import check as C
 from chipbench import harness as H
 from chipbench import program as P
@@ -16,8 +22,16 @@ from chipbench import tiny
 from chipbench import weights as W
 from chipbench import work
 
-CONFIGS = ["qwen3-4b", "nemotron-4-15b"]
+CONFIGS = sorted(f[:-len(".json")]
+                 for f in os.listdir(os.path.join(H.BENCH_DIR, "configs"))
+                 if f.endswith(".json"))
 SEED = 2 ** 31 + 77          # wider than 32 signed bits, as seeds may be
+PINS = H.load_json(H.BENCH_DIR, "fixtures", "dense-gqa-pins.json")
+
+
+def _leaves(tree) -> dict:
+    return {"/".join(str(e.key) for e in path): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -26,12 +40,37 @@ def test_stacked_draw_matches_layer_draw(name):
     stacked = W.draw_model(cfg, SEED)
     layer = W.layer_drawer(cfg, SEED)
     top = W.top_drawer(cfg, SEED)
-    for i in range(cfg["num_hidden_layers"]):
-        one = jax.tree_util.tree_map(lambda a: a[i], stacked["layers"])
-        jax.tree_util.tree_map(np.testing.assert_array_equal, one, layer(i))
+    for g, (first, count) in enumerate(chipbench.family(cfg).groups(cfg)):
+        for j in range(count):
+            one = jax.tree_util.tree_map(lambda a: a[j], stacked[f"group{g}"])
+            jax.tree_util.tree_map(np.testing.assert_array_equal, one,
+                                   layer(first + j))
     for key in top:
         jax.tree_util.tree_map(np.testing.assert_array_equal, stacked[key],
                                top[key])
+
+
+@pytest.mark.parametrize("name", sorted(PINS["tiny"]))
+def test_draw_is_the_pinned_draw(name):
+    """Every leaf at the small sizes has the shape, dtype and bytes (sha256)
+    pinned before the draw moved into the dense family."""
+    got = _leaves(W.draw_model(tiny.config(name), PINS["seed"]))
+    want = PINS["tiny"][name]
+    assert sorted(got) == sorted(want)
+    for path, leaf in got.items():
+        a = np.asarray(leaf)
+        assert [list(a.shape), str(a.dtype),
+                hashlib.sha256(a.tobytes()).hexdigest()] == want[path], path
+
+
+@pytest.mark.parametrize("name", sorted(PINS["published"]))
+def test_published_shape_tree_is_pinned(name):
+    """At the published widths (shapes only, nothing drawn) the tree of
+    names, shapes and dtypes is the pinned one."""
+    cfg = H.load_json(H.BENCH_DIR, "configs", f"{name}.json")
+    got = _leaves(jax.eval_shape(lambda: W.draw_model(cfg, SEED)))
+    assert {p: [list(s.shape), str(s.dtype)] for p, s in got.items()} == \
+        PINS["published"][name]
 
 
 def test_reference_dequant_reads_the_program_layout():
@@ -41,9 +80,11 @@ def test_reference_dequant_reads_the_program_layout():
     cfg = tiny.config("qwen3-4b")
     ref = C.reference(cfg["reference"])
     lw = W.layer_drawer(cfg, SEED)(0)
-    for name in W.projections(cfg):
-        ql = P._quantized(lw[name], cfg["quantization"]["group_size"])
-        np.testing.assert_array_equal(np.asarray(ref.dequant(lw[name])),
+    int4 = [w for w in lw.values() if isinstance(w, dict) and "qweight" in w]
+    assert len(int4) == 7
+    for w in int4:
+        ql = P._quantized(w, cfg["quantization"]["group_size"])
+        np.testing.assert_array_equal(np.asarray(ref.dequant(w)),
                                       np.asarray(dequantize(ql)))
 
 
@@ -119,10 +160,29 @@ def test_work_counts_by_hand():
 
 def test_useful_flops_skip_padding():
     cfg = tiny.config("qwen3-4b")
-    proj = W.projections(cfg)
+    fam = chipbench.family(cfg)
+    proj = fam.projections(cfg)
     step = {"batch": 4, "width": 32, "rows": [[0, 20, True], [7, 1, True]]}
     per_token = 2 * sum(k * n for k, n in proj.values())
     attn, _ = work.attention_rows(step["rows"], 4, 2, 32)
     head = 2 * 128 * cfg["vocab_size"] * 2
-    assert work.useful_flops(cfg, step, proj) == (
+    assert fam.useful_flops(cfg, step) == (
         2 * (21 * per_token + attn) + head)
+
+
+@pytest.mark.parametrize("width,attention", [(1, "paged_attention"),
+                                             (32, "paged_prefill")])
+def test_step_calls_by_kernel_class(width, attention):
+    """Each layer's projections at M = batch x width and one attention
+    call per layer, under the class of the kernel that width runs."""
+    cfg = tiny.config("qwen3-4b")
+    fam = chipbench.family(cfg)
+    step = {"batch": 4, "width": width, "rows": [[0, width, True],
+                                                 [7, 1, True]]}
+    calls = fam.step_calls(cfg, step)
+    assert set(calls) == {"gptq", attention}
+    one_layer = [work.gptq_call(4 * width, k, n, 128)
+                 for k, n in fam.projections(cfg).values()]
+    assert calls["gptq"] == one_layer * 2
+    assert calls[attention] == [work.attention_rows(step["rows"], 4, 2,
+                                                    32)] * 2

@@ -5,12 +5,15 @@ from __future__ import annotations
 
 import json
 import os
+import types
 
 import numpy as np
 import pytest
 
+import chipbench
 from chipbench import harness as H
 from chipbench import tracereduce as TR
+from chipbench import work
 
 FIXTURE = os.path.join(H.BENCH_DIR, "fixtures", "trace-decode-long.json")
 KERNELS = H.load_json(H.BENCH_DIR, "kernels.json")
@@ -58,6 +61,17 @@ def test_short_names():
     assert TR.short_name(loop) == "%while.5 while tuple"
 
 
+def test_kernel_classes_join_the_familys():
+    """A family's ``KERNELS`` join ``kernels.json``'s classes; the dense
+    family has none, and a class named twice is refused."""
+    dense = chipbench.load("families", "dense_gqa")
+    assert TR.classes(dense) == KERNELS
+    extra = types.SimpleNamespace(KERNELS={"expert_gptq": ["^%expert\\b"]})
+    assert TR.classes(extra) == {**KERNELS, **extra.KERNELS}
+    with pytest.raises(ValueError, match="gptq"):
+        TR.classes(types.SimpleNamespace(KERNELS={"gptq": ["x"]}))
+
+
 def test_no_step_no_reduction():
     assert TR.reduce([_ev("device", "k1", 0, 5)], KERNELS) is None
 
@@ -97,9 +111,14 @@ def test_recorded_trace_reduces():
 
 def test_recorded_trace_roofline_shares():
     """The fixture's steps and events give roofline shares and an MFU
-    strictly between 0 and 100%."""
+    strictly between 0 and 100%, each with the least time (or useful
+    operations) and the value pinned before the work counts moved into
+    the dense family, to the last digit."""
     data = _fixture()
-    run = H.Run(config=data["config"], traffic={}, seconds=1.0, records=[],
+    pinned = H.load_json(H.BENCH_DIR, "fixtures", "dense-gqa-pins.json")[
+        "fixture_trace"]
+    run = H.Run(config={**data["config"], "family": "dense_gqa"}, traffic={},
+                seconds=1.0, records=[],
                 window=(0.0, 1.0), setup_s=0.0, counters_open={},
                 counters_close={}, peaks=H.device_peaks("TPU v5 lite"),
                 chips=1, trace=TR.reduce(data["events"], KERNELS),
@@ -108,6 +127,20 @@ def test_recorded_trace_roofline_shares():
                  "device_idle_share"):
         value = H.reader(name)(run)
         assert 0 < value < 100, (name, value)
+        assert value == pinned["values"][name], name
+    by_class = [run.family.step_calls(run.config, s) for s in run.steps]
+
+    def least(*classes):
+        return work.roofline_seconds(
+            [c for calls in by_class for k in classes
+             for c in calls.get(k, [])],
+            run.peaks["bf16_flops_per_s"], run.peaks["hbm_bytes_per_s"])
+
+    assert least("gptq") == pinned["numerators"]["gptq_roofline"]
+    assert least("paged_attention", "paged_prefill") == \
+        pinned["numerators"]["paged_attn_roofline"]
+    assert sum(run.family.useful_flops(run.config, s) for s in run.steps) \
+        == pinned["numerators"]["step_mfu"]
 
 
 def test_prompt_tokens_count_where_they_are_prefilled():
