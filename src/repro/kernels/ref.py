@@ -144,7 +144,8 @@ def paged_prefill_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     q: (B, S, H, D) — query i of row b sits at absolute position
     ``seq_start[b] + i``; ``lengths``: (B,) total valid keys per row
     (``seq_start + write_lens``), masking right-padded bucket positions and
-    unwritten reserve pages.  Fully-masked query rows yield exact zeros,
+    unwritten reserve pages; a query at or past it is a pad query.
+    Fully-masked query rows (pad queries among them) yield exact zeros,
     matching the kernel's zero-normalizer convention.  -> (B, S, H, D).
     """
     k_pages, v_pages = _token_major_pools(k_pages, v_pages, k_scales,
@@ -160,8 +161,9 @@ def paged_prefill_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                         k.astype(jnp.float32)) * scale
     qpos = seq_start[:, None] + jnp.arange(s)[None, :]           # (B, S)
     kpos = jnp.arange(k.shape[1])                                # (K,)
+    # a pad query (at or past the row's length) attends nothing
     mask = ((kpos[None, None, :] <= qpos[:, :, None])
-            & (kpos[None, None, :] < lengths[:, None, None]))    # (B, S, K)
+            & (qpos[:, :, None] < lengths[:, None, None]))       # (B, S, K)
     logits = jnp.where(mask[:, None, None], logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
     p = p * mask[:, None, None]

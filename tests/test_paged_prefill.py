@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
+from repro.kernels import paged_attention as PA
 from repro.kernels.paged_attention import paged_prefill
 from repro.kernels.ref import flash_attention_ref, paged_prefill_ref
 from repro.models import attention as A
@@ -67,6 +68,118 @@ def test_paged_prefill_matches_ref(granularity, ragged, h, hkv):
     ref = paged_prefill_ref(q, kp, vp, bt, st, lens, k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# Live-block cases: 16-token pages make 8-page blocks of 128 keys, and a
+# 24-page table holds 384 keys, so rows span several blocks of the loop.
+PS, MAXP = 16, 24
+# name: (seq_start, write_lens, S, q_chunk)
+LIVE_CASES = {
+    # rows end mid-page and mid-block; everything past them is NaN
+    "stale-nan": ([0, 100, 250], [32, 20, 7], 32, 32),
+    # one row prefills, the others decode: their later q-chunks are all pad
+    "decode-rows": ([40, 130, 300, 7], [64, 1, 1, 1], 64, 32),
+    # lengths ending mid-page (27) and mid-block (182), one past the table
+    "table-end": ([0, 150, 370], [27, 32, 32], 32, 32),
+    # prefix hits whose first chunk straddles a block boundary (128, 256)
+    "prefix-straddle": ([100, 120, 250, 5], [32, 32, 16, 32], 32, 32),
+}
+
+
+def _live_case(case, pool, h=4, hkv=2, d=32, pages=100, seed=6):
+    """Inputs of one live-block case on an fp32, bf16 or int8 pool, plus
+    the oracle computed on the clean pools.  In ``stale-nan`` every pool
+    entry outside the rows' live keys is then made NaN (on int8 pools the
+    scales, where a whole page or token is dead) — the kernel must never
+    let it reach an output."""
+    starts, wlens, s, q_chunk = LIVE_CASES[case]
+    rng = np.random.default_rng(seed)
+    q, kp, vp, bt, st, lens = _random_prefill(
+        rng, len(starts), s, h, hkv, d, pages, PS, MAXP, starts, wlens)
+    ks = vs = None
+    if pool == "bf16":
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    elif pool != "fp32":
+        axes = (-1,) if pool == "int8-token" else (2, 3)
+        kp, ks = quantize(kp, axes=axes)
+        vp, vs = quantize(vp, axes=axes)
+    ref = paged_prefill_ref(q, kp, vp, bt, st, lens, k_scales=ks, v_scales=vs)
+    if case == "stale-nan":
+        live = np.zeros((pages, PS), bool)       # (page, slot) holding a key
+        for row, n in zip(np.asarray(bt), np.asarray(lens)):
+            for key in range(min(int(n), MAXP * PS)):
+                live[row[key // PS], key % PS] = True
+        if pool in ("fp32", "bf16"):
+            kp = jnp.where(live[None, :, :, None], kp, jnp.nan).astype(kp.dtype)
+            vp = jnp.where(live[None, :, :, None], vp, jnp.nan).astype(vp.dtype)
+        else:
+            live_scale = live.any(axis=1) if ks.ndim == 2 else live
+            ks = jnp.where(live_scale[None], ks, jnp.nan)
+            vs = jnp.where(live_scale[None], vs, jnp.nan)
+    return (q, kp, vp, bt, st, lens), dict(k_scales=ks, v_scales=vs,
+                                           q_chunk=q_chunk), ref
+
+
+@pytest.mark.parametrize("pool", ["fp32", "bf16", "int8-token", "int8-page"])
+@pytest.mark.parametrize("case", list(LIVE_CASES))
+def test_paged_prefill_live_blocks_match_ref(case, pool):
+    """The block loop over each chunk's live pages agrees with the gather
+    oracle across multi-block rows, pad chunks, table overrun, prefix
+    starts straddling a block boundary and NaN in every dead pool entry
+    (outputs stay finite), on float pools and both int8 granularities."""
+    args, kw, ref = _live_case(case, pool)
+    out = np.asarray(paged_prefill(*args, **kw))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("pool", ["fp32", "int8-token"])
+def test_paged_prefill_fetches_only_live_pages(monkeypatch, pool):
+    """The grid is (rows, kv heads, q-chunks), with no page axis, and a
+    program starts one DMA per pool for each page of the keys its chunk can
+    see and none beyond: a chunk wholly past its row's length (the decode
+    rows' pad chunks) fetches nothing.  DMA starts are counted as the
+    interpreter runs them."""
+    starts, wlens, s, q_chunk = LIVE_CASES["decode-rows"]
+    hkv = 2
+    args, kw, ref = _live_case("decode-rows", pool, hkv=hkv, d=48)
+    started = []
+    make_copy = PA.pltpu.make_async_copy
+
+    class CountedCopy:
+        def __init__(self, *a):
+            self.copy = make_copy(*a)
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    monkeypatch.setattr(PA.pltpu, "make_async_copy", CountedCopy)
+    PA.paged_prefill.clear_cache()
+    fn = jax.jit(lambda *a: paged_prefill(*a, **kw))
+    out = jax.block_until_ready(fn(*args))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    pages = 0
+    for start, n in zip(starts, np.asarray(args[5])):
+        for c in range(-(-s // q_chunk)):
+            q0 = start + c * q_chunk
+            if q0 < n:                           # else a chunk of pad queries
+                pages += -(-min(n, q0 + q_chunk, MAXP * PS) // PS)
+    per_page = 2 if pool == "fp32" else 4        # K, V (and their scales)
+    assert len(started) == hkv * pages * per_page
+    eqns = list(jax.make_jaxpr(fn)(*args).eqns)
+    grids = []
+    while eqns:                                  # search nested jaxprs
+        e = eqns.pop()
+        if e.primitive.name == "pallas_call":
+            grids.append(e.params["grid_mapping"].grid)
+        eqns += [x for p in e.params.values() if hasattr(p, "eqns")
+                 for x in p.eqns]
+    assert grids == [(len(starts), hkv, -(-s // q_chunk))]
 
 
 def test_paged_prefill_chunking_invariant():

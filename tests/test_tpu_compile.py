@@ -36,7 +36,6 @@ PROJECTIONS = [
     (QWEN3_4B.d_model, H * D), (QWEN3_4B.d_model, HKV * D),
     (H * D, QWEN3_4B.d_model), (QWEN3_4B.d_model, QWEN3_4B.d_ff),
     (QWEN3_4B.d_ff, QWEN3_4B.d_model)]
-POOLS = [(16, jnp.bfloat16), (32, jnp.int8)]     # (page_size, pool dtype)
 
 
 @pytest.fixture(scope="module")
@@ -123,13 +122,22 @@ def test_paged_attention_compiles(spec, page_size, dtype, per_page, rows,
              spec((rows, table)), spec((rows,)), **scales)
 
 
-@pytest.mark.parametrize("page_size,dtype", POOLS)
-def test_paged_prefill_compiles(spec, page_size, dtype):
-    pool, scales = _pools(spec, page_size, dtype)
+@pytest.mark.parametrize("page_size,dtype,rows,width,heads,pages,table", [
+    pytest.param(16, jnp.bfloat16, 2, 256, H, 257, 32, id="16-bfloat16"),
+    pytest.param(32, jnp.int8, 2, 256, H, 257, 32, id="32-int8"),
+    # qwen3-4b.decode-long: 32 rows x 256, its 2504-page pool, a 4096-token
+    # table
+    pytest.param(16, jnp.bfloat16, 32, 256, H, 2504, 256, id="decode-long"),
+    # nemotron-4-15b.doc-prefill: 8 rows x 512, 48/8 heads (GQA rep 6), its
+    # 768-page pool, a 4096-token table
+    pytest.param(16, jnp.bfloat16, 8, 512, 48, 768, 256, id="doc-prefill")])
+def test_paged_prefill_compiles(spec, page_size, dtype, rows, width, heads,
+                                pages, table):
+    pool, scales = _pools(spec, page_size, dtype, pages)
     _compile(functools.partial(paged_attention.paged_prefill,
                                interpret=False),
-             spec((2, 256, H, D), jnp.bfloat16), pool, pool, spec((2, 32)),
-             spec((2,)), spec((2,)), **scales)
+             spec((rows, width, heads, D), jnp.bfloat16), pool, pool,
+             spec((rows, table)), spec((rows,)), spec((rows,)), **scales)
 
 
 def _compile_fused_step(spec, width):
